@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.api import CheckpointSession
 from repro.configs import get_smoke_config
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.encdec import build_model
 from repro.optim import AdamW
 from repro.optim.schedule import constant
@@ -43,7 +43,7 @@ def main():
     mesh_a = mesh_of((4, 2))
     model_a = build_model(cfg, policy, mesh_a, compute_dtype=jnp.float32,
                           remat=False)
-    with use_mesh(mesh_a):
+    with jax.sharding.set_mesh(mesh_a):
         params = jax.jit(model_a.init,
                          out_shardings=model_a.param_shardings())(
             jax.random.key(0))
@@ -77,7 +77,7 @@ def main():
     from repro.data import TokenPipeline
     batch = {k: jnp.asarray(v)
              for k, v in TokenPipeline(cfg, 4, 16).next().items()}
-    with use_mesh(mesh_b):
+    with jax.sharding.set_mesh(mesh_b):
         loss = jax.jit(lambda p, b: model_b.loss(p, b)[0])(out["params"],
                                                            batch)
     print(f"first loss on the replacement mesh: {float(loss):.4f}")

@@ -2,8 +2,8 @@
 
 ``criu check`` validates that the kernel supports everything a dump/restore
 will need *before* anyone trusts it with a workload.  Our equivalents are
-runtime-library probes: JAX version and device availability, mesh
-axis-type support, serialization stack (msgpack / zlib / zstd), and the
+runtime-library probes: JAX version and device availability, the
+serialization stack (msgpack / zlib / zstd), and the
 device-backend registry.  ``capabilities()`` reports; ``check()`` judges.
 """
 from __future__ import annotations
@@ -20,7 +20,6 @@ def capabilities() -> Dict[str, Any]:
 
     from repro.core.backends import available_backends
     from repro.core.plugins import PLUGIN_API_VERSION
-    from repro.launch.mesh import HAS_AXIS_TYPES
 
     try:
         devices = jax.devices()
@@ -49,7 +48,6 @@ def capabilities() -> Dict[str, Any]:
             "device_count": device_count,
             "process_count": jax.process_count(),
         },
-        "mesh": {"axis_types": HAS_AXIS_TYPES},
         "serialization": {
             "msgpack": msgpack_version,
             "zlib": True,                     # stdlib, always present
@@ -113,9 +111,6 @@ def check(run_dir: Optional[str] = None, options=None) -> CheckReport:
     if not caps["serialization"]["zstd"]:
         warns.append("zstandard not installed; compress=True falls "
                      "back to zlib")
-    if not caps["mesh"]["axis_types"]:
-        warns.append("this JAX has no mesh axis_types support; meshes are "
-                     "built without explicit AxisType (compat shim)")
     if "jax" not in caps["backends"]:
         problems.append("no 'jax' device backend registered")
 

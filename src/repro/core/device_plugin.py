@@ -64,18 +64,6 @@ def _index_from_json(j) -> Tuple[slice, ...]:
 
 
 # ---------------------------------------------------------------- capture
-def _start_transfer(arr) -> None:
-    """Kick off the device→host DMA for `arr` without blocking.  Issuing
-    every shard's copy before the first `np.asarray` materialization makes
-    the capture loop double-buffered: while one shard's bytes are being
-    turned into a host ndarray, the next shards' copies are already in
-    flight, so the frozen window shrinks to roughly the copy itself."""
-    try:
-        arr.copy_to_host_async()
-    except Exception:                                  # pragma: no cover
-        pass                   # backend without async transfer: sync copy
-
-
 def capture_array(arr: jax.Array) -> Dict[str, Any]:
     """Snapshot one device array into host memory (shards deduped)."""
     shards = []
@@ -101,13 +89,15 @@ def capture_pytree(tree: PyTree) -> Dict[str, Dict[str, Any]]:
     Two passes: the first starts every shard's device→host transfer
     asynchronously, the second materializes host ndarrays (by which time
     the copies have been overlapping each other — the double-buffered
-    capture of the pipelined data plane)."""
+    capture of the pipelined data plane): while one shard's bytes are
+    turned into a host ndarray, the next shards' copies are in flight, so
+    the frozen window shrinks to roughly the copy itself."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
     for _, leaf in flat:
         if isinstance(leaf, jax.Array):
             for sh in leaf.addressable_shards:
                 if sh.replica_id == 0:
-                    _start_transfer(sh.data)
+                    sh.data.copy_to_host_async()
     out: Dict[str, Dict[str, Any]] = {}
     for path, leaf in flat:
         key = _key_str(path)
@@ -138,7 +128,9 @@ def restore_array(entry: Dict[str, Any], target_mesh=None,
     Fast path: the target sharding's shard indices match the saved shard
     index set exactly — place each saved buffer on its device directly.
     Slow (elastic) path: reassemble the global array and device_put with
-    the new layout.
+    the new layout.  A leaf with neither a target sharding nor a saved
+    named layout that maps onto `target_mesh` lands on the default
+    device.
     """
     shape = tuple(entry["shape"])
     dtype_from_str(entry["dtype"])      # validates the stored dtype
@@ -151,22 +143,13 @@ def restore_array(entry: Dict[str, Any], target_mesh=None,
 
     saved = {tuple(map(tuple, sh["index"])): sh["data"]
              for sh in entry["shards"]}
-    try:
-        index_map = sharding.devices_indices_map(shape)
-        pieces = []
-        ok = True
-        for dev, idx in index_map.items():
-            key = tuple(_index_to_json(idx, shape))
-            key = tuple(map(tuple, key))
-            if key not in saved:
-                ok = False
-                break
-            pieces.append(jax.device_put(saved[key], dev))
-        if ok:
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, pieces)
-    except Exception:
-        pass
+    wanted = {dev: tuple(map(tuple, _index_to_json(idx, shape)))
+              for dev, idx in sharding.devices_indices_map(shape).items()}
+    if all(key in saved for key in wanted.values()):
+        pieces = [jax.device_put(saved[key], dev)
+                  for dev, key in wanted.items()]
+        return jax.make_array_from_single_device_arrays(
+            shape, sharding, pieces)
     # elastic / mismatched layout: reassemble then reshard
     return jax.device_put(assemble_global(entry), sharding)
 

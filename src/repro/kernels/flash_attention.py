@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -149,9 +150,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, qt.shape[2], hd), q.dtype),
         scratch_shapes=[
-            pl_scratch((block_q, hd)),      # acc
-            pl_scratch((block_q, 1)),       # m (running max)
-            pl_scratch((block_q, 1)),       # l (running denominator)
+            pltpu.VMEM((block_q, hd), jnp.float32),     # acc
+            pltpu.VMEM((block_q, 1), jnp.float32),      # m (running max)
+            pltpu.VMEM((block_q, 1), jnp.float32),      # l (running denom)
         ],
         interpret=interpret,
     )(qt, kt, vt)
@@ -159,11 +160,3 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = jnp.moveaxis(out, 1, 2)
     return out[:, :Sq] if pq else out
 
-
-def pl_scratch(shape):
-    """VMEM f32 scratch (TPU) that also works in interpret mode."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:                                    # pragma: no cover
-        return pl.MemorySpace.ANY(shape, jnp.float32)

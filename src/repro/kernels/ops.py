@@ -1,15 +1,13 @@
 """Jit'd dispatch layer over the Pallas kernels.
 
-On a real TPU backend the kernels lower through Mosaic; on CPU (this
-container) they execute with ``interpret=True`` — the kernel body runs
-op-by-op in Python with identical semantics, which is how the per-kernel
-allclose tests validate them.  Set ``REPRO_FORCE_INTERPRET=0`` to force
-compiled mode (TPU only).
+On a TPU backend the kernels lower through Mosaic; on any other backend
+they execute with ``interpret=True`` — the kernel body runs op-by-op with
+identical semantics, which is how the per-kernel allclose tests validate
+them on the CPU.  The backend alone decides.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -20,9 +18,6 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

@@ -4,17 +4,24 @@ TPU adaptation (DESIGN.md §2): the Tri Dao SSD GPU kernel relies on warp-
 level parallel prefix products; on TPU we exploit the *sequential* grid
 instead — the recurrent state h (P×N, f32) lives in VMEM scratch carried
 across the innermost (chunk) grid dimension, while the intra-chunk work is
-three MXU matmuls per step:
+MXU matmuls per step:
 
     CB      = C · Bᵀ                 (Q×N)·(N×Q)  -> (Q,Q)
-    y_intra = (CB ⊙ L(dt)) · (dt⊙x)  (Q,Q)·(Q,P)
+    y_intra = (CB ⊙ L) · (dt⊙x)      (Q,Q)·(Q,P)
     y_inter = decay_in ⊙ (C · hᵀ)    (Q,N)·(N,P)
     h_new   = exp(Σ dA) h + xᵀ·(decay_out⊙dt⊙B)   (P,Q)·(Q,N)
 
 Grid: (batch, heads, nChunks) — chunks innermost/sequential.
-Block shapes: Q (chunk len) and N (state) are 128-aligned; P (head dim,
-64 for mamba2-2.7b) rides the MXU at half occupancy — recorded in
-EXPERIMENTS.md §Roofline.
+
+Layout: every block's last two dims are (8,128)-aligned or whole, as
+Mosaic requires.  x/y are head-major (B, nh, S, P) so a block is one
+head's (Q, P) chunk; B/C stay (B, S, N) with (Q, N) blocks.  The
+per-head scalar A never enters the kernel: the wrapper folds it into the
+chunk-local cumulative log-decay ``cum = cumsum(dt·A)`` (an XLA scan over
+Q), which the kernel reads as a column (Q, 1) and as a row (1, Q) — the
+two orientations the intra-chunk decay matrix L[i,j] = exp(cum_i − cum_j)
+needs, without an in-kernel transpose or cumsum.  dt comes as a column.
+P (head dim, 64 for mamba2-2.7b) rides the MXU at half occupancy.
 """
 from __future__ import annotations
 
@@ -24,11 +31,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import pl_scratch
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
+def _ssd_kernel(x_ref, dt_ref, cc_ref, cr_ref, b_ref, c_ref,
                 y_ref, hout_ref, h_ref, *, chunk: int):
     ic = pl.program_id(2)
     nc = pl.num_programs(2)
@@ -37,40 +43,40 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0, :, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)      # (Q,)
-    A = a_ref[0]                                  # scalar
+    x = x_ref[0, 0].astype(jnp.float32)           # (Q, P)
+    dt = dt_ref[0, 0]                             # (Q, 1)
+    cum = cc_ref[0, 0]                            # (Q, 1) inclusive cumsum
+    cum_row = cr_ref[0, 0]                        # (1, Q) same, as a row
     Bm = b_ref[0].astype(jnp.float32)             # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)             # (Q, N)
 
-    dA = dt * A                                   # (Q,)
-    cum = jnp.cumsum(dA)                          # inclusive
-    total = cum[-1]
+    # Σ dA over the chunk, as an (1, N) row: Mosaic cannot broadcast a
+    # (1, 1) value over sublanes and lanes at once, so take the last row
+    # of the lane-broadcast column with a masked sublane sum
+    last = jax.lax.broadcasted_iota(jnp.int32, Bm.shape, 0) == chunk - 1
+    total = jnp.sum(jnp.where(last, cum, 0.0), axis=0, keepdims=True)
     decay_in = jnp.exp(cum)                       # chunk entry -> i
-    decay_out = jnp.exp(total - cum)              # j -> chunk exit
 
     # intra-chunk: L[i,j] = exp(cum_i - cum_j) for j <= i
-    diff = cum[:, None] - cum[None, :]
     tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    L = jnp.where(tri, jnp.exp(diff), 0.0)        # (Q, Q)
+    L = jnp.where(tri, jnp.exp(cum - cum_row), 0.0)   # (Q, Q)
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    G = CB * L                                    # (Q, Q)
-    y_intra = jax.lax.dot_general(G * dt[None, :], x,
+    y_intra = jax.lax.dot_general(CB * L, dt * x,
                                   (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
     # inter-chunk: y_inter[i] = decay_in[i] * C[i] · h_prev
     h = h_ref[...]                                # (P, N)
-    y_inter = decay_in[:, None] * jax.lax.dot_general(
+    y_inter = decay_in * jax.lax.dot_general(
         Cm, h, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)       # (Q, P)
 
-    y_ref[0, :, 0] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update
-    w = (decay_out * dt)[:, None] * Bm            # (Q, N)
+    w = jnp.exp(total - cum) * dt * Bm            # decay_out⊙dt⊙B (Q, N)
     contrib = jax.lax.dot_general(x, w, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     h_ref[...] = h * jnp.exp(total) + contrib
@@ -102,29 +108,36 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
         Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0)))
     S_pad = x.shape[1]
     nC = S_pad // Q
-    grid = (B, nh, nC)
+
+    # head-major layouts; the chunk-local cumulative log-decay replaces A
+    xt = jnp.moveaxis(x, 2, 1)                                # (B,nh,S,P)
+    dtt = jnp.moveaxis(dt.astype(jnp.float32), 2, 1)          # (B,nh,S)
+    dA = dtt * A.astype(jnp.float32)[None, :, None]
+    cum = jnp.cumsum(dA.reshape(B, nh, nC, Q), axis=-1).reshape(B, nh, S_pad)
 
     kernel = functools.partial(_ssd_kernel, chunk=Q)
     y, h_final = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, nh, nC),
         in_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, 0, c)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
             pl.BlockSpec((1, Q, N), lambda b, h, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, 1, P), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, Q, P), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S_pad, nh, P), x.dtype),
+            jax.ShapeDtypeStruct((B, nh, S_pad, P), x.dtype),
             jax.ShapeDtypeStruct((B, nh, P, N), jnp.float32),
         ],
-        scratch_shapes=[pl_scratch((P, N))],       # carried SSM state
+        scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],  # carried state
         interpret=interpret,
-    )(x, dt, A.astype(jnp.float32), Bm, Cm)
+    )(xt, dtt[..., None], cum[..., None], cum[:, :, None, :], Bm, Cm)
 
+    y = jnp.moveaxis(y, 1, 2)
     return (y[:, :S] if pad else y), h_final

@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512")
-# ^ MUST run before any jax import: jax locks the device count on first init.
+# a CPU-compiler estimate: it must never take the accelerator, which
+# belongs to one process at a time (and --all starts one child per cell)
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ both MUST run before any jax import: jax locks the platform and the
+# device count on first init.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -151,7 +155,6 @@ def build_lowered(arch: str, shape: str, mesh, policy_name: str,
         remat = apply_variant(variant) and remat
 
     from repro.configs import get_config
-    from repro.launch.mesh import use_mesh
     from repro.launch.shapes import SHAPES, batch_specs, batch_shardings
     from repro.models.encdec import build_model
     from repro.optim import AdamW
@@ -196,14 +199,14 @@ def build_lowered(arch: str, shape: str, mesh, policy_name: str,
         jitted = jax.jit(train_step,
                          in_shardings=(param_sh, opt_sh, batch_sh),
                          donate_argnums=(0, 1))
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_abs, opt_abs, batch_abs)
     elif cell.kind == "prefill":
         batch_abs = batch_specs(cfg, cell.global_batch, cell.seq_len)
         batch_sh = fit_shardings_tree(
             batch_shardings(cfg, policy, mesh), batch_abs, mesh)
         jitted = jax.jit(model.prefill, in_shardings=(param_sh, batch_sh))
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_abs, batch_abs)
     else:                                     # decode / serve_step
         B, S = cell.global_batch, cell.seq_len
@@ -220,7 +223,7 @@ def build_lowered(arch: str, shape: str, mesh, policy_name: str,
                          in_shardings=(param_sh, cache_sh, tok_sh,
                                        scalar_sh),
                          donate_argnums=(1,))
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jitted.lower(params_abs, cache_abs,
                                    jax.ShapeDtypeStruct((B,), jnp.int32),
                                    jax.ShapeDtypeStruct((), jnp.int32))

@@ -6,59 +6,19 @@ DCN axis (data-parallel across slices), "data"/"model" are ICI axes.
 
 Functions, not module-level constants: importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
-
-``make_mesh`` is the single compat shim for JAX versions without
-``jax.sharding.AxisType`` / the ``axis_types=`` kwarg (added after 0.4.37):
-every mesh in the repo — production, tests, examples, benchmarks — goes
-through it so the AxisType probe lives in exactly one place.
+Every mesh in the repo — production, tests, examples, benchmarks — goes
+through ``make_mesh``, so all of them have Auto axis types.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_type_support():
-    """(AxisType-or-None, make_mesh-accepts-axis_types)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return None, False
-    try:
-        ok = "axis_types" in inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        ok = False
-    return AxisType, ok
-
-
-AXIS_TYPE, HAS_AXIS_TYPES = _axis_type_support()
-
-
-def make_mesh(shape, axis_names, *, axis_types="auto"):
-    """``jax.make_mesh`` that tolerates JAX without ``axis_types``.
-
-    axis_types: "auto" (request AxisType.Auto per axis where supported),
-    None (never pass the kwarg), or an explicit tuple forwarded verbatim
-    when the running JAX understands it.
-    """
-    if axis_types is None or not HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, axis_names)
-    if axis_types == "auto":
-        axis_types = (AXIS_TYPE.Auto,) * len(axis_names)
-    return jax.make_mesh(shape, axis_names, axis_types=axis_types)
-
-
-def use_mesh(mesh):
-    """Context manager making `mesh` the ambient mesh.
-
-    ``jax.sharding.set_mesh`` where it exists; on older JAX the Mesh
-    object itself is the (equivalent) context manager.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

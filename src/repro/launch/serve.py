@@ -34,11 +34,13 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config, get_smoke_config
     from repro.data import TokenPipeline
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.models.encdec import build_model
     from repro.runtime.server import DecodeServer
     from repro.sharding import get_policy
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh(data=len(jax.devices()), model=1)
     policy = get_policy(args.policy)

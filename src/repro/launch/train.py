@@ -4,10 +4,12 @@
       --steps 200 --ckpt-every 25 --ckpt-mode async [--restore] \
       [--policy baseline] [--fail-at 120]
 
-On real hardware the same entry point runs the full config on the
-production mesh (no --smoke); in this container --smoke selects the
-reduced config on the host devices.  --restore resumes from the newest
-valid unified snapshot in --run-dir (the CRIUgpu restart path).
+Without --smoke the full config runs in bf16 on a data-parallel mesh
+over every local device; --smoke selects the reduced same-family config
+in f32, sized for a CPU.  --restore resumes from the newest valid
+unified snapshot in --run-dir (the CRIUgpu restart path).  A crash
+drains the in-flight async save before exiting, so the image that
+--restore will pick is on disk.
 """
 from __future__ import annotations
 
@@ -44,10 +46,12 @@ def main(argv=None) -> int:
 
     from repro.api import CheckpointOptions
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.runtime.trainer import TrainConfig, Trainer
     from repro.sharding import get_policy
 
+    use_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     n_dev = len(jax.devices())
     mesh = make_host_mesh(data=n_dev, model=1)
@@ -72,6 +76,9 @@ def main(argv=None) -> int:
         out = trainer.run(args.steps - trainer.step, fail_at=args.fail_at)
     except Exception as e:
         print(f"[train] crashed: {e} — restart with --restore", file=sys.stderr)
+        # the writer thread is a daemon: exiting now would drop the async
+        # save still in flight, and with it the image --restore needs
+        trainer.session.wait_pending()
         return 1
     print(json.dumps({
         "arch": cfg.name, "steps": out["steps"], "final_loss": out["loss"],

@@ -144,8 +144,8 @@ def moe_block(params, cfg, x: jax.Array, policy: ShardingPolicy,
     body = functools.partial(
         _local_moe, cfg=cfg, ep_axes=ep, fsdp_axes=fsdp,
         dp_axes=dp if dp_sharded else (), dropless=dropless)
-    y, aux = _shard_map(
-        body, mesh=mesh,
+    y, aux = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(tok_spec, P(None, None),
                   P(ep[0], fsdp if fsdp else None, None),
                   P(ep[0], fsdp if fsdp else None, None),
@@ -155,15 +155,3 @@ def moe_block(params, cfg, x: jax.Array, policy: ShardingPolicy,
       params["w_down"])
     return y.reshape(B, S, d), aux
 
-
-def _shard_map(body, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any JAX: the
-    top-level API (check_vma) where present, the experimental one
-    (check_rep) otherwise."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)

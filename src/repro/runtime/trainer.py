@@ -21,7 +21,6 @@ import numpy as np
 from repro.api import CheckpointOptions, CheckpointSession
 from repro.core import SnapshotEngine
 from repro.data import TokenPipeline
-from repro.launch.mesh import use_mesh
 from repro.models.config import ModelConfig
 from repro.models.encdec import build_model
 from repro.optim import AdamW
@@ -143,8 +142,14 @@ class Trainer:
         return params, opt_state, {**metrics, **om}
 
     def initialize(self) -> None:
-        self.params = self.model.init(jax.random.key(self.tcfg.seed))
-        self.opt_state = self.opt.init(self.params)
+        """Fresh params and optimizer state, created where the step
+        expects them: on a mesh each leaf is born in its own layout, so
+        no device ever holds the whole model."""
+        self.params = jax.jit(
+            self.model.init, out_shardings=self.model.param_shardings())(
+                jax.random.key(self.tcfg.seed))
+        self.opt_state = jax.jit(
+            self.opt.init, out_shardings=self._opt_shardings())(self.params)
         self.step = 0
 
     def restore(self, step: Optional[int] = None, mesh=None) -> int:
@@ -156,10 +161,13 @@ class Trainer:
         and are joined right before the first step executes
         (resume-before-read)."""
         if self.params is None:
-            # template for typed restore
-            self.params = self.model.init(jax.random.key(self.tcfg.seed))
-            self.opt_state = self.opt.init(self.params)
-        template = {"params": self.params, "opt": self.opt_state}
+            # typed restore needs only the tree structure: an abstract
+            # template allocates nothing on the device
+            params = self.model.init_abstract()
+            template = {"params": params,
+                        "opt": self.opt.init_abstract(params)}
+        else:
+            template = {"params": self.params, "opt": self.opt_state}
         shardings = None
         if self.mesh is not None:
             shardings = {"params": self.model.param_shardings(),
@@ -274,7 +282,7 @@ class Trainer:
             t0 = time.perf_counter()
             if straggle_at is not None and self.step == straggle_at:
                 time.sleep(0.25)                       # injected straggler
-            with use_mesh(self.mesh):
+            with jax.sharding.set_mesh(self.mesh):
                 self.params, self.opt_state, metrics = self._step_fn(
                     self.params, self.opt_state, batch)
             loss = float(metrics["loss"])
@@ -333,6 +341,9 @@ def run_with_restarts(make_trainer, total_steps: int,
         try:
             trainer.run(total_steps - trainer.step, fail_at=fail_at)
         except SimulatedFailure:
+            # the crashed trainer's async write would otherwise race its
+            # replacement's restore and dumps in this same process
+            trainer.session.wait_pending()
             pending.pop(fail_at, None)
             restarts += 1
             trainer = make_trainer()                   # replacement node

@@ -3,6 +3,9 @@ import os
 # Tests run single-device (the dry-run sets its own 512-device flag in a
 # subprocess); keep CPU math deterministic.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# no persistent compilation cache in tests (nor in the children they
+# start), even where an entry point points it at the checkout
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import jax
 import pytest

@@ -70,7 +70,7 @@ def test_capabilities_report_shape():
     assert caps["plugin_api_version"] == PLUGIN_API_VERSION
     assert caps["jax"]["version"] == jax.__version__
     assert caps["jax"]["device_count"] >= 1
-    assert isinstance(caps["mesh"]["axis_types"], bool)
+    assert caps["jax"]["platform"] == jax.devices()[0].platform
     assert set(caps["backends"]) >= {"jax", "host"}
     for spec in caps["backends"].values():
         assert spec["api_version"] == PLUGIN_API_VERSION

@@ -62,8 +62,7 @@ def test_shard_map_path_matches_fallback():
     cfg, params, x = _setup()
     mesh = make_mesh((1, 1), ("data", "model"))
     y0, aux0 = MOE.moe_block(params, cfg, x, POLICY, None, dropless=True)
-    from repro.launch.mesh import use_mesh
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         y1, aux1 = MOE.moe_block(params, cfg, x, POLICY.for_mesh(mesh),
                                  mesh, dropless=True)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),

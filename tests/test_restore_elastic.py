@@ -39,7 +39,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_smoke_config
     from repro.optim import AdamW
     from repro.optim.schedule import constant
@@ -61,7 +61,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
 
     mesh_a = make_mesh((4, 2), ("data", "model"))
     model_a = build(mesh_a)
-    with use_mesh(mesh_a):
+    with jax.sharding.set_mesh(mesh_a):
         params = jax.jit(model_a.init,
                          out_shardings=model_a.param_shardings())(
             jax.random.key(0))
@@ -94,7 +94,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
              for k, v in TokenPipeline(cfg, 4, 16).next().items()}
     def loss_fn(p, b):
         return model_b.loss(p, b)[0]
-    with use_mesh(mesh_b):
+    with jax.sharding.set_mesh(mesh_b):
         loss, grads = jax.jit(jax.value_and_grad(loss_fn))(out["params"],
                                                            batch)
     assert np.isfinite(float(loss))
