@@ -247,18 +247,12 @@ class HostNumpyBackend(DirtyTrackingMixin, Plugin):
 
     @staticmethod
     def _place_entry(reader, state: str, path: str):
-        from repro.core.device_plugin import assemble_global
-        entry = reader.load_entry(state, path)
-        if entry["kind"] == "device_array":
-            return assemble_global(entry)
-        if entry["kind"] == "np":
-            return entry["data"]
-        return entry["value"]
+        from repro.core.device_plugin import assemble_global, rebuild_entry
+        return rebuild_entry(reader.load_entry(state, path), assemble_global)
 
     def resume_devices_late(self, ctx: HookContext) -> None:
-        from repro.core.device_plugin import _unflatten_paths, assemble_global
+        from repro.core.device_plugin import assemble_global, restore_eager
         t0 = time.perf_counter()
-        place_s = 0.0
         reader = ctx.reader
         threads = getattr(ctx, "restore_threads", 0) or self.restore_threads
         if getattr(ctx, "lazy", False):
@@ -268,26 +262,8 @@ class HostNumpyBackend(DirtyTrackingMixin, Plugin):
             ctx.stats["host_to_device_s"] = time.perf_counter() - t0
             ctx.stats["place_s"] = ctx.stats.get("place_critical_s", 0.0)
             return
-        for name in reader.state_names():
-            keys = reader.entry_names(name)
-            if threads > 1 and len(keys) > 1:
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(max_workers=threads) as ex:
-                    entries = list(ex.map(
-                        lambda k: reader.load_entry(name, k), keys))
-            else:
-                entries = [reader.load_entry(name, k) for k in keys]
-            restored: Dict[str, Any] = {}
-            t_place = time.perf_counter()
-            for key, entry in zip(keys, entries):
-                if entry["kind"] == "device_array":
-                    restored[key] = assemble_global(entry)
-                elif entry["kind"] == "np":
-                    restored[key] = entry["data"]
-                else:
-                    restored[key] = entry["value"]
-            place_s += time.perf_counter() - t_place
-            ctx.restored[name] = _unflatten_paths(restored)
+        place_s = restore_eager(ctx, reader, threads,
+                                lambda s, p, e: assemble_global(e))
         self.lock.unlock()
         ctx.stats["host_to_device_s"] = time.perf_counter() - t0
         ctx.stats["place_s"] = place_s

@@ -17,7 +17,8 @@ of device state as ``jax.Array`` shards; the plugin:
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, Tuple
+from functools import partial
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import numpy as np
@@ -26,6 +27,7 @@ from repro.core.backends import DirtyTrackingMixin, JAX_BACKEND_FEATURES
 from repro.core.lock import DeviceLock
 from repro.core.plugins import HookContext, Plugin
 from repro.core.topology import (resolve_sharding, sharding_descriptor)
+from repro.obs import trace as obs_trace
 from repro.serialization.pack import dtype_to_str, dtype_from_str
 
 PyTree = Any
@@ -239,13 +241,13 @@ class DevicePlugin(DirtyTrackingMixin, Plugin):
         """Load + rebuild one logical leaf — the unit the lazy
         materializer streams, so arrays come back incrementally as their
         shards land."""
-        entry = reader.load_entry(state, path)
-        if entry["kind"] == "device_array":
-            return restore_array(entry, ctx.target_mesh,
-                                 self._flat_shardings(ctx, state).get(path))
-        if entry["kind"] == "np":
-            return entry["data"]
-        return entry["value"]
+        return rebuild_entry(reader.load_entry(state, path),
+                             partial(self._place_array, ctx, state, path))
+
+    def _place_array(self, ctx: HookContext, state: str, path: str,
+                     entry: Dict[str, Any]):
+        return restore_array(entry, ctx.target_mesh,
+                             self._flat_shardings(ctx, state).get(path))
 
     def resume_devices_late(self, ctx: HookContext) -> None:
         """host→device restore, with on-demand parallel entry loading (the
@@ -269,33 +271,51 @@ class DevicePlugin(DirtyTrackingMixin, Plugin):
             ctx.stats["host_to_device_s"] = time.perf_counter() - t0
             ctx.stats["place_s"] = ctx.stats.get("place_critical_s", 0.0)
             return
-        place_s = 0.0
-        for name in reader.state_names():
-            flat_sh = self._flat_shardings(ctx, name)
-            keys = reader.entry_names(name)
-            if threads > 1 and len(keys) > 1:
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(max_workers=threads) as ex:
-                    entries = list(ex.map(
-                        lambda k: reader.load_entry(name, k), keys))
-            else:
-                entries = [reader.load_entry(name, k) for k in keys]
-            restored: Dict[str, Any] = {}
-            t_place = time.perf_counter()
-            for key, entry in zip(keys, entries):
-                if entry["kind"] == "device_array":
-                    arr = restore_array(entry, ctx.target_mesh,
-                                        flat_sh.get(key))
-                elif entry["kind"] == "np":
-                    arr = entry["data"]
-                else:
-                    arr = entry["value"]
-                restored[key] = arr
-            place_s += time.perf_counter() - t_place
-            ctx.restored[name] = _unflatten_paths(restored)
+        place_s = restore_eager(ctx, reader, threads,
+                                partial(self._place_array, ctx))
         self.lock.unlock()
         ctx.stats["host_to_device_s"] = time.perf_counter() - t0
         ctx.stats["place_s"] = place_s
+
+
+def rebuild_entry(entry: Dict[str, Any],
+                  place_array: Callable[[Dict[str, Any]], Any]):
+    """One loaded entry as the leaf it restores to: a device array
+    through ``place_array``, host data and values as stored."""
+    if entry["kind"] == "device_array":
+        return place_array(entry)
+    if entry["kind"] == "np":
+        return entry["data"]
+    return entry["value"]
+
+
+def restore_eager(ctx: HookContext, reader, threads: int,
+                  place_array: Callable[[str, str, Dict[str, Any]], Any]
+                  ) -> float:
+    """Eager restore of every state into ``ctx.restored``: read and
+    decode every entry (``threads`` loaders when > 1, span
+    ``restore.read``), then rebuild every leaf (span ``restore.place``;
+    ``place_array(state, path, entry)`` places a device array).  Returns
+    the placement's seconds."""
+    keys = {name: reader.entry_names(name) for name in reader.state_names()}
+    n = sum(len(k) for k in keys.values())
+    loaded: Dict[str, list] = {}
+    with obs_trace.span("restore.read", entries=n, threads=threads):
+        for name, names in keys.items():
+            if threads > 1 and len(names) > 1:
+                from concurrent.futures import ThreadPoolExecutor
+                with ThreadPoolExecutor(max_workers=threads) as ex:
+                    loaded[name] = list(ex.map(
+                        lambda k: reader.load_entry(name, k), names))
+            else:
+                loaded[name] = [reader.load_entry(name, k) for k in names]
+    t_place = time.perf_counter()
+    with obs_trace.span("restore.place", leaves=n):
+        for name, entries in loaded.items():
+            ctx.restored[name] = _unflatten_paths({
+                key: rebuild_entry(entry, partial(place_array, name, key))
+                for key, entry in zip(keys[name], entries)})
+    return time.perf_counter() - t_place
 
 
 def _unflatten_paths(flat: Dict[str, Any]) -> Dict[str, Any]:

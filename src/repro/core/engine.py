@@ -62,6 +62,15 @@ class CheckpointAborted(RuntimeError):
     pass
 
 
+def _count_trigger() -> None:
+    """Count a dump begun under ``dump.trigger.<t>``: the ``trigger`` in
+    the caller's span context (the trainer sets periodic, straggler or
+    signal), ``call`` where none is set."""
+    if obs_metrics.REGISTRY is not None:
+        trigger = obs_trace.current_context().get("trigger", "call")
+        obs_metrics.counter_add(f"dump.trigger.{trigger}")
+
+
 class PendingWriteStalled(TimeoutError):
     """wait_pending(timeout_s=...) found the background writer still
     running past the deadline.  The thread is left joinable: call
@@ -244,6 +253,7 @@ class SnapshotEngine:
             # state is incomplete and must not be captured as an image)
             self.restore_barrier()
 
+        _count_trigger()
         ctx = HookContext("dump", step)
         ctx.roots = self._provider()
         self.registry.init_all("dump")
@@ -365,6 +375,7 @@ class SnapshotEngine:
         if self._lazy is not None:
             self.restore_barrier()
 
+        _count_trigger()
         ctx = HookContext("dump", step)
         ctx.roots = self._provider()
         self.registry.init_all("dump")
@@ -582,9 +593,13 @@ class SnapshotEngine:
             from repro.core.lazy import critical_pack_names, split_schedule
             critical, _ = split_schedule(reader,
                                          self.options.critical_states)
-            reader.verify_entries(critical_pack_names(reader, critical))
+            names = critical_pack_names(reader, critical)
         else:
-            reader.verify_all()
+            names = list(reader.manifest["locations"])
+        sizes = reader.manifest.get("entry_bytes", {})
+        with obs_trace.span("restore.verify", entries=len(names),
+                            bytes=sum(int(sizes.get(n, 0)) for n in names)):
+            reader.verify_entries(names)
 
     def _make_healer(self, step: int):
         """Background-stream heal hook: re-pull the image (and its delta
@@ -747,14 +762,11 @@ class SnapshotEngine:
                 if lazy:
                     self.store.unpin(step)        # backend without lazy
         self.registry.exit_all("restore", True)
-        if lazy:
-            ctx.stats["restore_critical_s"] = (time.perf_counter()
-                                               - t_restore0)
+        ctx.stats["restore_critical_s"] = time.perf_counter() - t_restore0
         ctx.stats["restore_mode"] = "lazy" if lazy else "eager"
         obs_metrics.counter_add("restore.count")
-        if lazy:
-            obs_metrics.observe("restore.critical_s",
-                                ctx.stats["restore_critical_s"])
+        obs_metrics.observe("restore.critical_s",
+                            ctx.stats["restore_critical_s"])
         obs_journal.emit("restore", "resumed", step=step,
                          mode=ctx.stats["restore_mode"])
         self.last_stats = dict(ctx.stats)
@@ -943,6 +955,12 @@ class ConcurrentCapture:
         host state, commit atomically, resume.  Returns the snapshot
         directory.  Raises CheckpointAborted (no image, job running) on
         lock timeout / unsafe op in flight."""
+        # the caller's span context at begin (job, trigger) also marks
+        # the validate-side spans, wherever finalize is called from
+        with obs_trace.context(**self._obs_ctx):
+            return self._finalize()
+
+    def _finalize(self) -> str:
         if self._done:
             raise RuntimeError("concurrent capture already finalized")
         eng = self._engine

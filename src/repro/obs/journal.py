@@ -9,7 +9,8 @@ Each line is one JSON object.  Common fields:
 
     v       journal format version (1)
     cls     event class: dump | restore | transfer | fault | job |
-            recovery | pack | metrics | meta
+            recovery | pack | serve | train | jit | orch | metrics |
+            meta
     kind    event kind within the class ("span", "transition",
             "injection", "pending_stall", "snapshot", ...)
     t       seconds since journal open (monotonic clock)
@@ -40,7 +41,7 @@ JOURNAL: Optional["RunJournal"] = None
 
 VERSION = 1
 CLASSES = ("dump", "restore", "transfer", "fault", "job", "recovery",
-           "pack", "orch", "metrics", "meta")
+           "pack", "serve", "train", "jit", "orch", "metrics", "meta")
 
 
 class RunJournal:

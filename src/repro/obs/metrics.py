@@ -35,6 +35,10 @@ METRIC_SCHEMA: Dict[str, tuple] = {
     "dump.pending_stall_s": ("histogram", "s",
                              "async writer join timeouts "
                              "(PendingWriteStalled)"),
+    # dump.trigger.<t> counts dumps begun by why they ran: the
+    # ``trigger`` of the caller's span context (periodic, straggler,
+    # signal), or ``call`` when none is set.  Dynamic keys, one row.
+    "dump.trigger.*": ("counter", "dumps", "dumps begun, by trigger"),
     "pack.chunks": ("counter", "chunks", "chunks through the pipeline"),
     "pack.queue_depth": ("gauge", "chunks",
                          "compress-queue depth at last sample"),
@@ -54,6 +58,9 @@ METRIC_SCHEMA: Dict[str, tuple] = {
     # schema row.
     "replica.*": ("counter", "mixed", "replicator last_stats mirror"),
     "chaos.injections": ("counter", "events", "faults actually armed"),
+    "jit.compiles": ("counter", "programs",
+                     "backend compiles or compile-cache loads while a "
+                     "tracer is installed"),
     "fleet.replicas_booted": ("counter", "replicas",
                               "fleet boots attempted"),
     "fleet.replicas_serving": ("gauge", "replicas",

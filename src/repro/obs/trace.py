@@ -19,7 +19,24 @@ orchestrator's ``RecoveryLog`` uses it so every recovery phase
 (detect/transfer/schedule/restore/background/replay) appears in the
 trace as a first-class span instead of parallel bookkeeping.
 
-This module deliberately imports nothing from ``repro`` so every layer
+While a tracer is installed, two bridges to JAX are live:
+
+* every span opened through ``Tracer.begin`` also holds a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  capture shows the program's spans as host events on the trace's own
+  clock, beside the device operations (``record()`` spans are not
+  bridged: their time has already passed);
+* a ``jax.monitoring`` duration listener turns JAX's jit phases into
+  retroactive ``jit.trace`` / ``jit.lower`` / ``jit.compile`` spans
+  (``jit.compile`` is a backend compile or a compile-cache load) and
+  counts the latter under the ``jit.compiles`` metric.
+
+``install()`` imports JAX and registers both; ``uninstall()`` removes
+them, so with no tracer installed no listener exists and no annotation
+is made.
+
+This module deliberately imports nothing from ``repro`` outside
+``repro.obs`` (JAX only lazily, at ``install()``) so every layer
 (serialization, transfer, orchestrator) can depend on it without cycles.
 """
 from __future__ import annotations
@@ -29,7 +46,20 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from repro.obs import metrics as _metrics
+
 TRACER: Optional["Tracer"] = None
+# jax.profiler.TraceAnnotation while a tracer is installed, else None
+_ANNOTATION: Optional[Callable[[str], Any]] = None
+# the jax.monitoring listener while registered, else None
+_JIT_LISTENER: Optional[Callable[..., None]] = None
+
+# jax.monitoring duration events (jax/_src/dispatch.py) -> span name
+JIT_EVENTS: Dict[str, str] = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
 
 # span name -> (layer, description); the stable schema the docs table and
 # the exporter's class filter (`repro events --class`) key off.  A span's
@@ -54,6 +84,13 @@ SPAN_SCHEMA: Dict[str, tuple] = {
                                    "place, resume"),
     "restore.critical_place": ("engine", "critical-set entry placement "
                                          "(inside restore.critical)"),
+    "restore.verify": ("engine", "image CRC check before a restore "
+                                 "(inside restore.critical)"),
+    "restore.read": ("engine", "eager restore: read and decode every "
+                               "entry (inside restore.critical)"),
+    "restore.place": ("engine", "eager restore: rebuild every leaf, "
+                                "start its host->device copy "
+                                "(inside restore.critical)"),
     "restore.background": ("engine", "lazy background stream"),
     "restore.entry": ("engine", "one background entry "
                                 "(detail mode only)"),
@@ -74,6 +111,19 @@ SPAN_SCHEMA: Dict[str, tuple] = {
                                    "(the TTFT window)"),
     "fleet.serve": ("orchestrator", "bursty request trace against the "
                                     "fleet (autoscale inside)"),
+    "train.step": ("runtime", "one training step: batch, dispatch, loss "
+                              "on the host"),
+    "train.sync": ("runtime", "the wait for the step's loss on the host "
+                              "(inside train.step)"),
+    "serve.step": ("runtime", "one decoded token: stage, dispatch, "
+                              "fetch, append"),
+    "serve.sync": ("runtime", "the wait for the token's argmax on the "
+                              "host (inside serve.step)"),
+    "jit.trace": ("jax", "jaxpr trace of a jitted function "
+                         "(retroactive)"),
+    "jit.lower": ("jax", "jaxpr -> MLIR lowering (retroactive)"),
+    "jit.compile": ("jax", "backend compile or compile-cache load "
+                           "(retroactive)"),
 }
 
 
@@ -99,7 +149,7 @@ class Span:
     """One live span; finished (and sunk) when its ``with`` block exits."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "thread",
-                 "t_start", "t_end", "_tracer")
+                 "t_start", "t_end", "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
                  span_id: int, parent_id: Optional[int]) -> None:
@@ -111,6 +161,7 @@ class Span:
         self.thread = threading.current_thread().name
         self.t_start = tracer.clock()
         self.t_end: Optional[float] = None
+        self._ann: Any = None       # profiler annotation while open
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
@@ -165,11 +216,18 @@ class Tracer:
         stack = self._stack()
         parent = stack[-1].span_id if stack else None
         sp = Span(self, name, attrs, next(self._ids), parent)
+        ann = _ANNOTATION
+        if ann is not None:
+            sp._ann = ann(name)
+            sp._ann.__enter__()
         stack.append(sp)
         return sp
 
     def _finish(self, sp: Span) -> None:
         sp.t_end = self.clock()
+        if sp._ann is not None:
+            sp._ann.__exit__(None, None, None)
+            sp._ann = None
         stack = self._stack()
         if sp in stack:                      # tolerate exits out of order
             stack.remove(sp)
@@ -256,14 +314,40 @@ def current_context() -> Dict[str, Any]:
     return dict(tr._ctx())
 
 
+def _on_jax_duration(event: str, duration: float, **kw: Any) -> None:
+    """jax.monitoring listener: a finished jit phase becomes a span that
+    ends now on the tracer's clock and lasted ``duration``."""
+    name = JIT_EVENTS.get(event)
+    tr = TRACER
+    if name is None or tr is None:
+        return
+    t_end = tr.clock()
+    tr.record(name, t_end - duration, t_end,
+              {"fun_name": kw.get("fun_name")})
+    if name == "jit.compile":
+        _metrics.counter_add("jit.compiles")
+
+
 def install(tracer: Tracer) -> None:
-    global TRACER
+    global TRACER, _ANNOTATION, _JIT_LISTENER
     if TRACER is not None and TRACER is not tracer:
         raise RuntimeError("a tracer is already installed; "
                            "uninstall it first")
+    if _JIT_LISTENER is None:
+        import jax.monitoring
+        import jax.profiler
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _JIT_LISTENER = _on_jax_duration
+        _ANNOTATION = jax.profiler.TraceAnnotation
     TRACER = tracer
 
 
 def uninstall() -> None:
-    global TRACER
+    global TRACER, _ANNOTATION, _JIT_LISTENER
     TRACER = None
+    _ANNOTATION = None
+    if _JIT_LISTENER is not None:
+        import jax.monitoring
+        jax.monitoring.unregister_event_duration_listener(_JIT_LISTENER)
+        _JIT_LISTENER = None

@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import CheckpointOptions, CheckpointSession
 from repro.models.config import ModelConfig
 from repro.models.encdec import build_model
+from repro.obs import trace as obs_trace
 from repro.sharding.policy import ShardingPolicy
 
 
@@ -150,11 +151,15 @@ class DecodeServer:
                 time.sleep(0.25)                   # injected straggler
             # first-touch join of the lazily-streaming cache
             self._finish_lazy_restore()
-            last = jnp.asarray(self.tokens[:, -1])
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              last, jnp.int32(self.pos))
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-            self.tokens = np.concatenate([self.tokens, nxt[:, None]], axis=1)
+            with obs_trace.span("serve.step", pos=self.pos):
+                last = jnp.asarray(self.tokens[:, -1])
+                logits, self.cache = self._decode(self.params, self.cache,
+                                                  last, jnp.int32(self.pos))
+                best = jnp.argmax(logits, axis=-1)
+                with obs_trace.span("serve.sync"):
+                    nxt = np.asarray(best, np.int32)
+                self.tokens = np.concatenate([self.tokens, nxt[:, None]],
+                                             axis=1)
             self.pos += 1
             executed += 1
         return {"steps": executed, "pos": self.pos, "preempted": preempted,

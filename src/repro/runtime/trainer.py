@@ -24,6 +24,7 @@ from repro.data import TokenPipeline
 from repro.models.config import ModelConfig
 from repro.models.encdec import build_model
 from repro.optim import AdamW
+from repro.obs import trace as obs_trace
 from repro.optim.schedule import warmup_cosine
 from repro.runtime.fault import JITCheckpointPolicy, StragglerMonitor
 from repro.sharding.policy import ShardingPolicy
@@ -267,41 +268,49 @@ class Trainer:
                     ckpt_path = snapshot_dir(self.session.run_dir,
                                              self.step)
                 else:
-                    with self.session.frozen(self.step) as snap:
+                    with obs_trace.context(trigger="signal"), \
+                            self.session.frozen(self.step) as snap:
                         pass                           # dump-and-yield
                     ckpt_path = snap.path
                 preempted = True
                 break
             if fail_at is not None and self.step == fail_at:
                 raise SimulatedFailure(f"injected failure at {self.step}")
-            batch_np = self.pipeline.next()
-            batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-            # first-touch join: batch prep (and everything since restore
-            # returned) overlapped the background optimizer-slot stream
-            self._finish_lazy_restore()
-            t0 = time.perf_counter()
-            if straggle_at is not None and self.step == straggle_at:
-                time.sleep(0.25)                       # injected straggler
-            with jax.sharding.set_mesh(self.mesh):
-                self.params, self.opt_state, metrics = self._step_fn(
-                    self.params, self.opt_state, batch)
-            loss = float(metrics["loss"])
+            with obs_trace.span("train.step", step=self.step):
+                batch_np = self.pipeline.next()
+                batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                # first-touch join: batch prep (and everything since
+                # restore returned) overlapped the background
+                # optimizer-slot stream
+                self._finish_lazy_restore()
+                t0 = time.perf_counter()
+                if straggle_at is not None and self.step == straggle_at:
+                    time.sleep(0.25)                   # injected straggler
+                with jax.sharding.set_mesh(self.mesh):
+                    self.params, self.opt_state, metrics = self._step_fn(
+                        self.params, self.opt_state, batch)
+                # after a restore this also waits for the placed state
+                # to land on the device
+                with obs_trace.span("train.sync"):
+                    loss = float(metrics["loss"])
             self.metrics_history["loss"].append(loss)
             dt = time.perf_counter() - t0
             self.step += 1
             executed += 1
             if self.straggler.record(dt):
-                self.jit_ckpt.on_signal(self.step)     # just-in-time ckpt
+                with obs_trace.context(trigger="straggler"):
+                    self.jit_ckpt.on_signal(self.step)  # just-in-time ckpt
             if (self.tcfg.ckpt_every
                     and self.step % self.tcfg.ckpt_every == 0):
-                if self.session.options.capture == "concurrent":
-                    # soft-freeze: brief pin pause, then the loop keeps
-                    # stepping while shards are speculated in background;
-                    # the handle is finalized by the poll above (or the
-                    # settle below if the run ends first)
-                    self.session.checkpoint_begin(self.step)
-                else:
-                    self.session.checkpoint(self.step)
+                with obs_trace.context(trigger="periodic"):
+                    if self.session.options.capture == "concurrent":
+                        # soft-freeze: brief pin pause, then the loop
+                        # keeps stepping while shards are speculated in
+                        # background; the handle is finalized by the poll
+                        # above (or the settle below if the run ends first)
+                        self.session.checkpoint_begin(self.step)
+                    else:
+                        self.session.checkpoint(self.step)
         # never leave a capture half-done across run_until boundaries
         self.session.checkpoint_finalize()
         return {"steps": executed, "step": self.step,

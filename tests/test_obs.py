@@ -36,11 +36,36 @@ def make_state(n=4, kb=8):
 
 
 # --------------------------------------------------------- disabled path
-def test_disabled_plane_is_inert():
+@pytest.fixture
+def made(monkeypatch):
+    """Counts of the ``Span``s and profiler annotations created."""
+    import jax
+    counts = {"span": 0, "annotation": 0}
+    span_init = trace.Span.__init__
+    annotation = jax.profiler.TraceAnnotation
+
+    def counted_span_init(self, *a, **kw):
+        counts["span"] += 1
+        span_init(self, *a, **kw)
+
+    def counted_annotation(*a, **kw):
+        counts["annotation"] += 1
+        return annotation(*a, **kw)
+
+    monkeypatch.setattr(trace.Span, "__init__", counted_span_init)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counted_annotation)
+    return counts
+
+
+def test_disabled_plane_is_inert(tmp_path, made):
     """No plane installed: every module global is None, span() returns
     the one shared no-op singleton, and the other entry points return
-    without touching any state."""
+    without touching any state.  An eager restore and a fresh jit
+    create no ``Span`` and no profiler annotation, and no jit listener
+    is registered."""
+    import jax
     assert trace.TRACER is None
+    assert trace._JIT_LISTENER is None and trace._ANNOTATION is None
     assert metrics.REGISTRY is None
     assert journal.JOURNAL is None
     spans = [trace.span("dump.pause", step=i) for i in range(32)]
@@ -55,6 +80,12 @@ def test_disabled_plane_is_inert():
     journal.emit("dump", "commit", step=1)
     with trace.context(job="j0"):
         assert trace.span("dump.pause") is trace.NOOP_SPAN
+    eng = SnapshotEngine(str(tmp_path / "run"))
+    eng.attach(lambda: {"train_state": make_state()})
+    eng.checkpoint(1)
+    SnapshotEngine(str(tmp_path / "run")).restore()
+    jax.jit(lambda x: x * 3)(np.ones(2, np.float32)).block_until_ready()
+    assert made == {"span": 0, "annotation": 0}
 
 
 def test_last_stats_parity_disabled_vs_seed(tmp_path):
@@ -92,6 +123,237 @@ def test_install_is_exclusive(tmp_path):
     finally:
         plane.close()
     assert trace.TRACER is None and journal.JOURNAL is None
+
+
+# ------------------------------------------------ restore, steps, jit
+@pytest.mark.parametrize("backend", ["jax", "host"])
+def test_eager_restore_splits_into_verify_read_place(tmp_path, backend):
+    """One each of restore.verify, restore.read and restore.place per
+    eager restore, children of restore.critical; the critical time is
+    in last_stats and the histogram for eager restores too."""
+    state = make_state(n=3)
+    eng = SnapshotEngine(str(tmp_path / "run"))
+    eng.attach(lambda: {"train_state": state})
+    eng.checkpoint(1)
+    tr, reg = trace.Tracer(), metrics.MetricsRegistry()
+    trace.install(tr)
+    metrics.install(reg)
+    try:
+        eng2 = SnapshotEngine(str(tmp_path / "run"), backend=backend)
+        out = eng2.restore()
+    finally:
+        trace.uninstall()
+        metrics.uninstall()
+    np.testing.assert_array_equal(np.asarray(out["train_state"]["w1"]),
+                                  state["w1"])
+    (crit,) = [sp for sp in tr.spans if sp.name == "restore.critical"]
+    kids = {sp.name: sp for sp in tr.spans if sp.parent_id == crit.span_id}
+    assert sorted(kids) == ["restore.place", "restore.read",
+                            "restore.verify"]
+    assert kids["restore.verify"].attrs["entries"] >= 3
+    # every array's bytes, plus the host-state and meta blobs
+    assert kids["restore.verify"].attrs["bytes"] >= sum(
+        v.nbytes for v in state.values())
+    assert kids["restore.read"].attrs["entries"] == 3
+    assert kids["restore.place"].attrs["leaves"] == 3
+    assert (kids["restore.read"].t_end
+            <= kids["restore.place"].t_start)
+    assert eng2.last_stats["restore_critical_s"] > 0
+    hist = reg.snapshot()["histograms"]["restore.critical_s"]
+    assert hist["count"] == 1
+    assert hist["sum"] == eng2.last_stats["restore_critical_s"]
+
+
+def test_jit_phases_become_spans_and_are_counted():
+    """A fresh jit under an installed tracer gives jit.trace, jit.lower
+    and jit.compile spans and counts the compile; the cached call gives
+    none; uninstall removes the listener."""
+    import jax
+    from jax._src import monitoring
+    tr, reg = trace.Tracer(), metrics.MetricsRegistry()
+    trace.install(tr)
+    metrics.install(reg)
+    try:
+        assert trace._on_jax_duration in \
+            monitoring.get_event_duration_listeners()
+        f = jax.jit(lambda x: x * 2 + 1)
+        x = np.ones(5, np.float32)
+        t_call = tr.clock()
+        f(x).block_until_ready()
+        first = [sp for sp in tr.spans if sp.name.startswith("jit.")]
+        n_spans = len(tr.spans)
+        f(x).block_until_ready()
+        assert len(tr.spans) == n_spans
+    finally:
+        trace.uninstall()
+        metrics.uninstall()
+    names = {sp.name for sp in first}
+    assert names == {"jit.trace", "jit.lower", "jit.compile"}
+    ours = [sp for sp in first if "lambda" in sp.attrs["fun_name"]]
+    assert {sp.name for sp in ours} == names
+    assert all(t_call - 1e-3 <= sp.t_start <= sp.t_end <= tr.clock()
+               for sp in ours)
+    compiles = sum(sp.name == "jit.compile" for sp in first)
+    assert reg.snapshot()["counters"]["jit.compiles"] == compiles
+    assert trace._on_jax_duration not in \
+        monitoring.get_event_duration_listeners()
+    assert trace._JIT_LISTENER is None
+
+
+def test_profiler_capture_holds_program_spans(tmp_path, made):
+    """While a tracer is installed each span also opens a profiler
+    annotation: a jax.profiler capture shows it as a host event.  A
+    retroactive span is not bridged."""
+    import jax
+    trace.install(trace.Tracer())
+    try:
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+        with trace.span("serve.step", pos=0):
+            with trace.span("serve.sync"):
+                np.asarray(jax.numpy.ones(2))
+        trace.record("recovery.detect", 0.0, 1.0)
+        jax.profiler.stop_trace()
+    finally:
+        trace.uninstall()
+    assert made["annotation"] == 2
+    (path,) = (tmp_path / "prof").rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    host = {ev.name for plane in pd.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert {"serve.step", "serve.sync"} <= host
+    assert "recovery.detect" not in host
+
+
+def _smoke_trainer(run_dir, ckpt_every=2):
+    import jax.numpy as jnp
+    from repro.api import CheckpointOptions
+    from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
+    from repro.runtime.trainer import TrainConfig, Trainer
+    from repro.sharding import get_policy
+    tcfg = TrainConfig(batch_size=2, seq_len=16, total_steps=16, lr=5e-3,
+                       warmup_steps=2, ckpt_every=ckpt_every,
+                       ckpt=CheckpointOptions(mode="async"),
+                       compute_dtype=jnp.float32, remat=False)
+    return Trainer(get_smoke_config("qwen1.5-0.5b"), tcfg,
+                   make_mesh((1,), ("data",)), get_policy("baseline"),
+                   run_dir)
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """A smoke trainer's traced run with periodic saves (every 2 steps),
+    a straggler save at step 3 and a preemption save at step 5."""
+    from types import SimpleNamespace
+    run = str(tmp_path_factory.mktemp("trained") / "run")
+    t = _smoke_trainer(run)
+    t.straggler = SimpleNamespace(record=lambda dt: t.step == 3)
+    tr, reg = trace.Tracer(), metrics.MetricsRegistry()
+    trace.install(tr)
+    metrics.install(reg)
+    try:
+        t.run_until(4)
+        out = t.run_until(8, preempt=lambda: t.step == 5)
+        t.session.wait_pending()
+    finally:
+        trace.uninstall()
+        metrics.uninstall()
+    assert out["preempted"] and out["step"] == 5
+    return {"run": run, "spans": list(tr.spans),
+            "counters": reg.snapshot()["counters"]}
+
+
+def test_trainer_saves_carry_their_trigger(trained):
+    """Periodic, straggler and preemption saves carry ``trigger`` on
+    their dump spans, the async writer's included, and count under
+    dump.trigger.<t>."""
+    phases = ("dump.pause", "dump.capture", "dump.ext_state", "dump.write")
+    by_step = {}
+    for sp in trained["spans"]:
+        if sp.name in phases:
+            by_step.setdefault(sp.attrs["step"], set()).add(
+                sp.attrs.get("trigger"))
+    assert by_step == {2: {"periodic"}, 3: {"straggler"},
+                       4: {"periodic"}, 5: {"signal"}}
+    writes = [sp for sp in trained["spans"] if sp.name == "dump.write"]
+    assert writes and all(sp.thread != threading.main_thread().name
+                          for sp in writes)
+    counts = {k: v for k, v in trained["counters"].items()
+              if k.startswith("dump.trigger.")}
+    assert counts == {"dump.trigger.periodic": 2,
+                      "dump.trigger.straggler": 1,
+                      "dump.trigger.signal": 1}
+
+
+def test_engine_dump_without_trigger_counts_as_call(tmp_path):
+    eng = SnapshotEngine(str(tmp_path / "run"))
+    eng.attach(lambda: {"train_state": make_state(n=1)})
+    with observed(str(tmp_path / "obs")) as plane:
+        eng.checkpoint(1)
+        with trace.context(trigger="periodic"):
+            eng.checkpoint(2)
+        counters = plane.registry.snapshot()["counters"]
+    assert counters["dump.trigger.call"] == 1
+    assert counters["dump.trigger.periodic"] == 1
+
+
+def test_resumed_train_step_holds_one_sync(trained, made):
+    """run_until(n + 1) after restore(): one train.step holding one
+    train.sync; with no tracer the same loop makes no span."""
+    from types import SimpleNamespace
+    t = _smoke_trainer(trained["run"], ckpt_every=0)
+    t.straggler = SimpleNamespace(record=lambda dt: False)   # no saves
+    step = t.restore()
+    t.run_until(step + 1)                       # tracing off
+    assert made == {"span": 0, "annotation": 0}
+    tr = trace.Tracer()
+    trace.install(tr)
+    try:
+        t.run_until(step + 2)
+    finally:
+        trace.uninstall()
+    steps = [sp for sp in tr.spans if sp.name == "train.step"]
+    syncs = [sp for sp in tr.spans if sp.name == "train.sync"]
+    assert [sp.attrs["step"] for sp in steps] == [step + 1]
+    assert len(syncs) == 1 and syncs[0].parent_id == steps[0].span_id
+    assert steps[0].t_start <= syncs[0].t_start <= syncs[0].t_end \
+        <= steps[0].t_end
+    # every span opened under the installed tracer was bridged
+    assert made["annotation"] == sum(not sp.name.startswith("jit.")
+                                     for sp in tr.spans)
+
+
+def test_decode_loop_spans_each_token(tmp_path, made):
+    """decode_until(pos + k) gives k serve.step spans, each holding one
+    serve.sync; with no tracer the same loop makes no span."""
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.data import TokenPipeline
+    from repro.launch.mesh import make_mesh
+    from repro.runtime.server import DecodeServer
+    from repro.sharding import get_policy
+    cfg = get_smoke_config("mamba2-2.7b")
+    mesh = make_mesh((1,), ("data",))
+    srv = DecodeServer(cfg, get_policy("baseline"), mesh,
+                       str(tmp_path / "srv"), max_seq=32)
+    srv.load(srv.model.init(jax.random.key(0)))
+    srv.start(TokenPipeline(cfg, 2, 8, seed=3).next())
+    srv.decode_until(srv.pos + 2)               # tracing off, warm
+    assert made == {"span": 0, "annotation": 0}
+    pos = srv.pos
+    tr = trace.Tracer()
+    trace.install(tr)
+    try:
+        srv.decode_until(pos + 3)
+    finally:
+        trace.uninstall()
+    steps = [sp for sp in tr.spans if sp.name == "serve.step"]
+    syncs = [sp for sp in tr.spans if sp.name == "serve.sync"]
+    assert [sp.attrs["pos"] for sp in steps] == [pos, pos + 1, pos + 2]
+    assert sorted(sp.parent_id for sp in syncs) == sorted(
+        sp.span_id for sp in steps)
+    assert srv.tokens.shape[1] == 8 + 1 + 2 + 3
 
 
 # ----------------------------------------------------- spans and nesting
