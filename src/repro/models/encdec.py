@@ -48,7 +48,8 @@ def _dec_layer_specs(cfg) -> Dict[str, Any]:
 def encdec_param_specs(cfg: ModelConfig) -> Dict[str, Any]:
     specs: Dict[str, Any] = {
         "embed": {"tok": L.ParamSpec((cfg.padded_vocab, cfg.d_model),
-                                     ("vocab", "d_model"), scale=0.02)},
+                                     ("vocab", "d_model"), scale=0.02,
+                                     cast=True)},
         "enc_blocks": L.stack_specs(_enc_layer_specs(cfg), cfg.encoder_layers),
         "dec_blocks": L.stack_specs(_dec_layer_specs(cfg), cfg.num_layers),
         "enc_final_norm": L.rmsnorm_spec(cfg.d_model),
@@ -56,7 +57,7 @@ def encdec_param_specs(cfg: ModelConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = L.ParamSpec((cfg.d_model, cfg.padded_vocab),
-                                       ("d_model", "vocab"))
+                                       ("d_model", "vocab"), cast=True)
     return specs
 
 
@@ -84,6 +85,11 @@ class EncDecLM:
 
     def param_axes(self) -> PyTree:
         return L.axes_tree(self._specs)
+
+    def compute_params(self, params) -> PyTree:
+        """``params`` as the serving programs take them: matrices, biases
+        and the embedding in the compute dtype, the rest as held."""
+        return L.compute_params(self._specs, params, self.compute_dtype)
 
     def param_shardings(self):
         ax = self.param_axes()

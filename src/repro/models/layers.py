@@ -13,6 +13,7 @@ Design rules:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -68,6 +69,10 @@ class ParamSpec:
     axes: Tuple[Optional[str], ...]          # logical axis names
     init: str = "normal"                     # normal | zeros | ones
     scale: Optional[float] = None            # stddev for "normal"
+    # the model reads this leaf only as ``.astype(compute_dtype)``, so
+    # its compute copy (``compute_params``) may hold it cast; leaves read
+    # in f32 (norm scales, SSM decay terms, conv taps, router) stay False
+    cast: bool = False
 
     def __post_init__(self):
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
@@ -137,9 +142,36 @@ def axes_tree(specs: PyTree) -> PyTree:
 def stack_specs(specs: PyTree, n: int) -> PyTree:
     """Add a leading scan ("layers") dim of size n to every ParamSpec."""
     def f(s: ParamSpec) -> ParamSpec:
-        return ParamSpec((n,) + s.shape, ("layers",) + s.axes, s.init, s.scale)
+        return dataclasses.replace(s, shape=(n,) + s.shape,
+                                   axes=("layers",) + s.axes)
     return jax.tree.map(f, specs,
                         is_leaf=lambda x: isinstance(x, ParamSpec))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _astype(leaves, dtype):
+    return [p.astype(dtype) for p in leaves]
+
+
+def compute_params(specs: PyTree, params: PyTree, dtype) -> PyTree:
+    """The tree a serving program takes: every leaf declared ``cast`` in
+    ``dtype``, every other leaf the same array as in ``params``.
+
+    The programs cast those leaves themselves, so the result computes
+    bit for bit what ``params`` does; it only saves the cast.  A tree
+    with nothing to cast (params already held in ``dtype``) comes back
+    as itself: no copy is made."""
+    dtype = jnp.dtype(dtype)
+    marks = jax.tree.map(lambda s, p: s.cast and p.dtype != dtype,
+                         specs, params,
+                         is_leaf=lambda x: isinstance(x, ParamSpec))
+    marks = jax.tree.leaves(marks)
+    if not any(marks):
+        return params
+    leaves, treedef = jax.tree.flatten(params)
+    cast = iter(_astype([p for p, m in zip(leaves, marks) if m], dtype))
+    return jax.tree.unflatten(
+        treedef, [next(cast) if m else p for p, m in zip(leaves, marks)])
 
 
 # ======================================================================
@@ -206,15 +238,17 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
 def attention_specs(cfg) -> Dict[str, ParamSpec]:
     d, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     s: Dict[str, ParamSpec] = {
-        "wq": ParamSpec((d, H * hd), ("d_model", "heads")),
-        "wk": ParamSpec((d, KV * hd), ("d_model", "kv_heads")),
-        "wv": ParamSpec((d, KV * hd), ("d_model", "kv_heads")),
-        "wo": ParamSpec((H * hd, d), ("heads", "d_model")),
+        "wq": ParamSpec((d, H * hd), ("d_model", "heads"), cast=True),
+        "wk": ParamSpec((d, KV * hd), ("d_model", "kv_heads"), cast=True),
+        "wv": ParamSpec((d, KV * hd), ("d_model", "kv_heads"), cast=True),
+        "wo": ParamSpec((H * hd, d), ("heads", "d_model"), cast=True),
     }
     if cfg.qkv_bias:
-        s["bq"] = ParamSpec((H * hd,), ("heads",), init="zeros")
-        s["bk"] = ParamSpec((KV * hd,), ("kv_heads",), init="zeros")
-        s["bv"] = ParamSpec((KV * hd,), ("kv_heads",), init="zeros")
+        s["bq"] = ParamSpec((H * hd,), ("heads",), init="zeros", cast=True)
+        s["bk"] = ParamSpec((KV * hd,), ("kv_heads",), init="zeros",
+                            cast=True)
+        s["bv"] = ParamSpec((KV * hd,), ("kv_heads",), init="zeros",
+                            cast=True)
     if cfg.qk_norm:
         s["q_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
         s["k_norm"] = ParamSpec((hd,), ("head_dim",), init="ones")
@@ -394,9 +428,9 @@ def softmax_xent_sharded(logits: jax.Array, targets: jax.Array,
 # ======================================================================
 def mlp_specs(d: int, ff: int) -> Dict[str, ParamSpec]:
     return {
-        "w_gate": ParamSpec((d, ff), ("d_model", "d_ff")),
-        "w_up": ParamSpec((d, ff), ("d_model", "d_ff")),
-        "w_down": ParamSpec((ff, d), ("d_ff", "d_model")),
+        "w_gate": ParamSpec((d, ff), ("d_model", "d_ff"), cast=True),
+        "w_up": ParamSpec((d, ff), ("d_model", "d_ff"), cast=True),
+        "w_down": ParamSpec((ff, d), ("d_ff", "d_model"), cast=True),
     }
 
 

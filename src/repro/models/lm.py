@@ -59,13 +59,14 @@ def lm_param_specs(cfg: ModelConfig) -> Dict[str, Any]:
               for j in range(P)}
     specs: Dict[str, Any] = {
         "embed": {"tok": L.ParamSpec((cfg.padded_vocab, cfg.d_model),
-                                     ("vocab", "d_model"), scale=0.02)},
+                                     ("vocab", "d_model"), scale=0.02,
+                                     cast=True)},
         "blocks": blocks,
         "final_norm": L.rmsnorm_spec(cfg.d_model),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = L.ParamSpec((cfg.d_model, cfg.padded_vocab),
-                                       ("d_model", "vocab"))
+                                       ("d_model", "vocab"), cast=True)
     return specs
 
 
@@ -135,6 +136,11 @@ class LM:
 
     def param_axes(self) -> PyTree:
         return L.axes_tree(self._specs)
+
+    def compute_params(self, params) -> PyTree:
+        """``params`` as the serving programs take them: matrices, biases
+        and the embedding in the compute dtype, the rest as held."""
+        return L.compute_params(self._specs, params, self.compute_dtype)
 
     def param_shardings(self):
         ax = self.param_axes()

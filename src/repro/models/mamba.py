@@ -23,11 +23,11 @@ def mamba_specs(cfg) -> Dict[str, ParamSpec]:
     d, di, N, nh = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_nheads
     w = cfg.ssm_conv_width
     return {
-        "w_x": ParamSpec((d, di), ("d_model", "ssm_inner")),
-        "w_z": ParamSpec((d, di), ("d_model", "ssm_inner")),
-        "w_B": ParamSpec((d, N), ("d_model", "state")),
-        "w_C": ParamSpec((d, N), ("d_model", "state")),
-        "w_dt": ParamSpec((d, nh), ("d_model", "ssm_heads")),
+        "w_x": ParamSpec((d, di), ("d_model", "ssm_inner"), cast=True),
+        "w_z": ParamSpec((d, di), ("d_model", "ssm_inner"), cast=True),
+        "w_B": ParamSpec((d, N), ("d_model", "state"), cast=True),
+        "w_C": ParamSpec((d, N), ("d_model", "state"), cast=True),
+        "w_dt": ParamSpec((d, nh), ("d_model", "ssm_heads"), cast=True),
         "dt_bias": ParamSpec((nh,), ("ssm_heads",), init="zeros"),
         "A_log": ParamSpec((nh,), ("ssm_heads",), init="zeros"),
         "D": ParamSpec((nh,), ("ssm_heads",), init="ones"),
@@ -35,7 +35,7 @@ def mamba_specs(cfg) -> Dict[str, ParamSpec]:
         "conv_B": ParamSpec((w, N), ("conv", "state")),
         "conv_C": ParamSpec((w, N), ("conv", "state")),
         "norm": ParamSpec((di,), ("ssm_inner",), init="ones"),
-        "w_out": ParamSpec((di, d), ("ssm_inner", "d_model")),
+        "w_out": ParamSpec((di, d), ("ssm_inner", "d_model"), cast=True),
     }
 
 

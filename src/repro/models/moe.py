@@ -38,9 +38,12 @@ def moe_specs(cfg) -> Dict[str, ParamSpec]:
     E, d, f = cfg.moe_num_experts, cfg.d_model, cfg.moe_d_ff
     return {
         "router": ParamSpec((d, E), (None, None)),   # replicated (tiny)
-        "w_gate": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff")),
-        "w_up": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff")),
-        "w_down": ParamSpec((E, f, d), ("experts", "moe_ff", "d_model")),
+        "w_gate": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff"),
+                            cast=True),
+        "w_up": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff"),
+                            cast=True),
+        "w_down": ParamSpec((E, f, d), ("experts", "moe_ff", "d_model"),
+                            cast=True),
     }
 
 

@@ -71,6 +71,10 @@ METRIC_SCHEMA: Dict[str, tuple] = {
                             "delta bytes shipped booting replicas"),
     "fleet.requests_served": ("counter", "requests",
                               "requests completed by the fleet"),
+    "serve.weights_cast": ("counter", "copies",
+                           "decode-server compute copies of the weights "
+                           "built: one per load or restore, none per "
+                           "token"),
 }
 
 

@@ -119,6 +119,9 @@ SPAN_SCHEMA: Dict[str, tuple] = {
                               "fetch, append"),
     "serve.sync": ("runtime", "the wait for the token's argmax on the "
                               "host (inside serve.step)"),
+    "serve.cast": ("runtime", "build of the decode server's compute "
+                              "copy of its weights, at the first prefill "
+                              "or token after a load or restore"),
     "jit.trace": ("jax", "jaxpr trace of a jitted function "
                          "(retroactive)"),
     "jit.lower": ("jax", "jaxpr -> MLIR lowering (retroactive)"),

@@ -18,6 +18,7 @@ import numpy as np
 from repro.api import CheckpointOptions, CheckpointSession
 from repro.models.config import ModelConfig
 from repro.models.encdec import build_model
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.sharding.policy import ShardingPolicy
 
@@ -35,7 +36,7 @@ class DecodeServer:
         self.model = model if model is not None else build_model(
             cfg, policy, mesh, compute_dtype=compute_dtype, remat=False)
         self.max_seq = max_seq
-        self.params = None
+        self.params = None             # the held (f32 master) weights
         self.cache = None
         self.tokens: Optional[np.ndarray] = None       # generated so far
         self.pos = 0
@@ -65,6 +66,28 @@ class DecodeServer:
             self.model._decode_server_jit = jits
         self._prefill, self._decode = jits
 
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, value) -> None:
+        # every assignment (load, each restore path, a caller) drops the
+        # compute copy: it is rebuilt from the new weights at first use
+        self._params = value
+        self._compute = None
+
+    def _compute_params(self):
+        """The weights as the prefill and decode programs take them: the
+        model's compute copy of ``params``, built once per load or
+        restore.  Never captured: the image holds ``params``."""
+        if self._compute is None:
+            with obs_trace.span("serve.cast"):
+                self._compute = self.model.compute_params(self._params)
+            if self._compute is not self._params:
+                obs_metrics.counter_add("serve.weights_cast")
+        return self._compute
+
     def _restore_cursor(self, st):
         self.pos = st["pos"]
         self.tokens = st["tokens"]
@@ -77,7 +100,7 @@ class DecodeServer:
         """Prefill a batch of prompts; cache is padded to max_seq."""
         prompt = batch["tokens"]
         B, S = prompt.shape
-        logits, cache = self._prefill(self.params,
+        logits, cache = self._prefill(self._compute_params(),
                                       {k: jnp.asarray(v)
                                        for k, v in batch.items()})
         self.cache = self._pad_cache(cache, self.max_seq)
@@ -153,8 +176,9 @@ class DecodeServer:
             self._finish_lazy_restore()
             with obs_trace.span("serve.step", pos=self.pos):
                 last = jnp.asarray(self.tokens[:, -1])
-                logits, self.cache = self._decode(self.params, self.cache,
-                                                  last, jnp.int32(self.pos))
+                logits, self.cache = self._decode(self._compute_params(),
+                                                  self.cache, last,
+                                                  jnp.int32(self.pos))
                 best = jnp.argmax(logits, axis=-1)
                 with obs_trace.span("serve.sync"):
                     nxt = np.asarray(best, np.int32)

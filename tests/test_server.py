@@ -95,3 +95,125 @@ def test_greedy_decode_matches_model_argmax(tmp_path, mesh1):
     toks = srv.decode(2)
     assert toks.shape == (1, 8 + 1 + 2)
     assert int(toks.max()) < cfg.vocab_size    # padded vocab never sampled
+
+
+# ------------------------------------------- the compute copy of the weights
+def _drive_model(srv, params, batch, n):
+    """Greedy decode straight through the server's model, f32 params in:
+    what a server that casts nothing ahead of time computes."""
+    model = srv.model
+    logits, cache = jax.jit(model.prefill)(
+        params, {k: jnp.asarray(v) for k, v in batch.items()})
+    cache = srv._pad_cache(cache, srv.max_seq)
+    S = batch["tokens"].shape[1]
+    toks = [np.asarray(jnp.argmax(logits, axis=-1), np.int32)]
+    step = jax.jit(model.decode_step)
+    for i in range(n):
+        logits, cache = step(params, cache, jnp.asarray(toks[-1]),
+                             jnp.int32(S + i))
+        toks.append(np.asarray(jnp.argmax(logits, axis=-1), np.int32))
+    return np.stack(toks, axis=1), cache
+
+
+def _leaves_equal(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    return len(la) == len(lb) and all(
+        x.dtype == y.dtype and np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(la, lb))
+
+
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "qwen1.5-0.5b",
+                                  "qwen3-moe-30b-a3b"])
+def test_bf16_compute_copy_is_bitwise_exact(arch, tmp_path):
+    """A bf16-compute server decodes from a bf16 copy of its matrices,
+    biases and embedding (norm scales, SSM terms, conv taps and the MoE
+    router stay f32): its tokens and cache are bit for bit those of the
+    model's programs fed the f32 params, and its image holds the f32
+    params it loaded.  With f32 compute the copy is the params tree."""
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(1, 1)
+    cfg = get_smoke_config(arch)
+    run = str(tmp_path / "srv")
+    srv = DecodeServer(cfg, POLICY, mesh, run, max_seq=32,
+                       compute_dtype=jnp.bfloat16)
+    # every leaf off the bf16 grid: init's zeros and ones (norm scales,
+    # A_log, dt_bias, D) would hide a leaf wrongly cast
+    params = srv.model.init(jax.random.key(1))
+    noise = jax.random.split(jax.random.key(3), len(jax.tree.leaves(params)))
+    params = jax.tree.unflatten(jax.tree.structure(params), [
+        p + 0.01 * jax.random.normal(k, p.shape, p.dtype)
+        for p, k in zip(jax.tree.leaves(params), noise)])
+    srv.load(params)
+    batch = _prompt(cfg, B=2, S=8)
+    srv.start(batch)
+    srv.decode(5)
+    want_tokens, want_cache = _drive_model(srv, params, batch, 5)
+    np.testing.assert_array_equal(srv.tokens[:, 8:], want_tokens)
+    assert _leaves_equal(srv.cache, want_cache)
+
+    held = jax.tree.leaves(params)
+    copy = jax.tree.leaves(srv._compute_params())
+    assert {str(p.dtype) for p in copy} == {"float32", "bfloat16"}
+    assert any(c is p for c, p in zip(copy, held))     # f32 leaves shared
+
+    srv.checkpoint(0)
+    cold = DecodeServer(cfg, POLICY, mesh, run, max_seq=32,
+                        model=srv.model)
+    cold.restore()
+    assert {str(p.dtype) for p in jax.tree.leaves(cold.params)} == \
+        {"float32"}
+    assert _leaves_equal(cold.params, params)
+
+    f32 = DecodeServer(cfg, POLICY, mesh, str(tmp_path / "f32"),
+                       max_seq=32)
+    f32.load(params)
+    assert f32._compute_params() is f32.params
+
+
+@pytest.mark.parametrize("path", ["eager", "cold", "lazy"])
+def test_restore_rebuilds_the_compute_copy(path, tmp_path):
+    """Weights A loaded and decoded, then an image taken with weights B
+    restored into the same server: decoding follows B, so the compute
+    copy was rebuilt and not left stale.  ``serve.weights_cast`` counts
+    one copy per load or restore and none per token."""
+    from repro.api import CheckpointOptions
+    from repro.launch.mesh import make_host_mesh
+    from repro.obs import metrics
+    mesh = make_host_mesh(1, 1)
+    cfg = get_smoke_config("mamba2-2.7b")
+    run = str(tmp_path / "srv")
+    writer = DecodeServer(cfg, POLICY, mesh, run, max_seq=32,
+                          compute_dtype=jnp.bfloat16)
+    weights_b = writer.model.init(jax.random.key(2))
+    writer.load(weights_b)
+    batch = _prompt(cfg, B=2, S=8)
+    writer.start(batch)
+    writer.decode(3)
+    writer.checkpoint(0)
+    expected = writer.decode(4).copy()
+
+    options = (CheckpointOptions(restore_mode="lazy") if path == "lazy"
+               else None)
+    srv = DecodeServer(cfg, POLICY, mesh, run, max_seq=32,
+                       model=writer.model, options=options)
+    reg = metrics.MetricsRegistry()
+    metrics.install(reg)
+    try:
+        def casts():
+            return reg.counters.get("serve.weights_cast", 0)
+
+        srv.load(writer.model.init(jax.random.key(1)))       # weights A
+        srv.start(batch)
+        assert casts() == 1
+        srv.decode(5)
+        assert casts() == 1                                  # none a token
+        if path == "cold":
+            srv.cache = None          # no live cache: the cold branch
+        srv.restore()
+        assert srv.pos == writer.pos - 4
+        got = srv.decode(4)
+        assert casts() == 2
+    finally:
+        metrics.uninstall()
+    np.testing.assert_array_equal(expected, got)
+    assert _leaves_equal(srv.params, weights_b)

@@ -9,7 +9,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.  Keep these tests in this one file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,4 +106,62 @@ def test_qwen_train_step_fits_one_chip(topo, tmp_path):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.alias_size_in_bytes > 0            # params/opt donated
+    assert total < V5E_HBM_BYTES, total
+
+
+def _converts_of(hlo_text):
+    """(result type, operand type) of every ``convert`` in compiled HLO
+    text, which names an operand without its type: look it up."""
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])\S* "
+                      r"([\w\-]+)\(([^)]*)\)", re.M)
+    types, converts = {}, []
+    for name, ty, op, args in inst.findall(hlo_text):
+        types[name] = ty
+        if op == "convert":
+            converts.append((ty, args.strip().lstrip("%")))
+    return [(ty, types.get(arg)) for ty, arg in converts]
+
+
+def test_mamba2_decode_step_casts_no_weight(topo, tmp_path):
+    """The serve cell's decode step at mamba2-2.7b widths (16 layers,
+    B=8, bf16 compute), compiled as the server runs it: on the compute
+    copy of the weights it fits one chip and converts no f32 weight.
+    The same program on the held f32 tree converts every matrix and the
+    embedding each call, which shows the search finds such converts."""
+    from repro.configs import get_config
+    from repro.runtime.server import DecodeServer
+    from repro.sharding import get_policy
+
+    cfg = dataclasses.replace(get_config("mamba2-2.7b"), num_layers=16,
+                              vocab_size=50277)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    one = SingleDeviceSharding(topo.devices[0])
+    srv = DecodeServer(cfg, get_policy("baseline"), mesh, str(tmp_path),
+                       max_seq=513, compute_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(one, a.shape, a.dtype), tree)
+
+    held = on_chip(srv.model.init_abstract())
+    weights = {f"f32{list(a.shape)}".replace(" ", "")
+               for a in jax.tree.leaves(held) if len(a.shape) >= 2}
+    cache = on_chip(srv.model.cache_abstract(8, 513))
+    tok, pos = _spec(one, (8,), jnp.int32), _spec(one, (), jnp.int32)
+
+    def compile_step(params):
+        with jax.sharding.set_mesh(mesh):
+            return srv._decode.lower(params, cache, tok, pos).compile()
+
+    def weight_converts(compiled):
+        return [c for c in _converts_of(compiled.as_text())
+                if c[1] in weights]
+
+    assert len(weight_converts(compile_step(held))) >= 4
+    compiled = compile_step(on_chip(
+        jax.eval_shape(srv.model.compute_params, held)))
+    assert _converts_of(compiled.as_text())        # the search ran
+    assert weight_converts(compiled) == []
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
