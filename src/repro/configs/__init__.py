@@ -22,6 +22,7 @@ _MODULES: Dict[str, str] = {
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
     "qwen2-vl-7b": "qwen2_vl_7b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

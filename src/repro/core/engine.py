@@ -262,8 +262,11 @@ class SnapshotEngine:
             with obs_trace.span("dump.pause", step=step):
                 self.registry.run(Hook.PAUSE_DEVICES, ctx)   # ① lock
             t_frozen = time.perf_counter()
-            with obs_trace.span("dump.capture", step=step):
+            with obs_trace.span("dump.capture", step=step) as sp:
                 self.registry.run(Hook.CHECKPOINT_DEVICES, ctx)  # ② dev→host
+                captured = ctx.stats.get("device_bytes", 0.0)
+                sp.set(bytes=captured)
+            obs_metrics.counter_add("dump.captured_bytes", captured)
             with obs_trace.span("dump.ext_state", step=step):
                 self.registry.run(Hook.DUMP_EXT_STATE, ctx)  # ③ host state
             ctx.stats["frozen_s"] = time.perf_counter() - t_frozen

@@ -36,7 +36,6 @@ def main(argv=None) -> int:
     from repro.data import TokenPipeline
     from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import make_host_mesh
-    from repro.models.encdec import build_model
     from repro.runtime.server import DecodeServer
     from repro.sharding import get_policy
 
@@ -45,14 +44,14 @@ def main(argv=None) -> int:
     mesh = make_host_mesh(data=len(jax.devices()), model=1)
     policy = get_policy(args.policy)
 
+    # a smoke run computes and holds f32; a full one computes in bf16
+    # over weights held in the dtype the model is published in
+    compute = jnp.float32 if args.smoke else jnp.bfloat16
+    held = jnp.float32 if args.smoke else jnp.dtype(cfg.param_dtype)
     srv = DecodeServer(cfg, policy, mesh, args.run_dir,
-                       max_seq=args.max_seq,
-                       compute_dtype=jnp.float32 if args.smoke
-                       else jnp.bfloat16)
-    model = build_model(cfg, policy, mesh,
-                        compute_dtype=jnp.float32 if args.smoke
-                        else jnp.bfloat16, remat=False)
-    srv.load(model.init(jax.random.key(args.seed)))
+                       max_seq=args.max_seq, compute_dtype=compute,
+                       param_dtype=held)
+    srv.load(srv.model.init(jax.random.key(args.seed)))
 
     batch = TokenPipeline(cfg, args.batch, args.prompt_len,
                           seed=args.seed).next()

@@ -31,6 +31,8 @@ class ModelConfig:
     rope_theta: float = 10000.0
     mrope: bool = False              # multimodal 3-component RoPE (Qwen2-VL)
     qk_norm: bool = False            # Qwen3-style per-head q/k RMSNorm
+    rope: bool = True                # False: no positional encoding (NoPE)
+    attn_scale: float = 0.0          # score scale; 0 = 1/sqrt(head_dim)
 
     # --- MoE ---
     moe_num_experts: int = 0         # 0 = dense everywhere
@@ -38,6 +40,9 @@ class ModelConfig:
     moe_d_ff: int = 0                # expert FFN width
     moe_layer_period: int = 1        # layer i is MoE iff i % period == period-1
     moe_capacity_factor: float = 1.25
+    moe_shared_d_ff: int = 0         # shared expert width (0 = none)
+    moe_experts_held: int = 0        # experts this chip holds (0 = all):
+    #                                  the router still scores all of them
 
     # --- SSM (Mamba2 / SSD) ---
     ssm_state: int = 0               # N (state size per head)
@@ -58,6 +63,12 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     vocab_pad_multiple: int = 256    # pad vocab for TP divisibility + MXU tiles
+    param_dtype: str = "float32"     # dtype the weights are published in
+    # muP multipliers (Granite 4.0): x0 = emb * E[tok]; each residual
+    # branch is scaled by `residual`; logits are divided by `logits_scaling`
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # ----------------------------------------------------------------- utils
     def layer_kind(self, i: int) -> str:
@@ -85,6 +96,11 @@ class ModelConfig:
         columns are masked to -inf in the head."""
         m = self.vocab_pad_multiple
         return ((self.vocab_size + m - 1) // m) * m
+
+    @property
+    def moe_held(self) -> int:
+        """Routed experts whose weights this model holds."""
+        return self.moe_experts_held or self.moe_num_experts
 
     @property
     def d_inner(self) -> int:
@@ -117,8 +133,9 @@ class ModelConfig:
             if self.encoder_layers and kind in ("attn", "swa"):
                 total += self._attn_params(cross=True) + d
             if self.is_moe_layer(i):
-                n_e = self.moe_top_k if active_only else self.moe_num_experts
+                n_e = self.moe_top_k if active_only else self.moe_held
                 total += n_e * self._mlp_params(self.moe_d_ff)
+                total += self._mlp_params(self.moe_shared_d_ff)
                 total += d * self.moe_num_experts   # router
             elif self.d_ff > 0:
                 total += self._mlp_params(self.d_ff)
@@ -180,6 +197,8 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         moe_num_experts=min(4, cfg.moe_num_experts),
         moe_top_k=min(2, cfg.moe_top_k),
         moe_d_ff=64 if cfg.moe_d_ff else 0,
+        moe_shared_d_ff=128 if cfg.moe_shared_d_ff else 0,
+        moe_experts_held=min(2, cfg.moe_experts_held),
         moe_capacity_factor=8.0,   # no-drop capacity => decode == forward
 
         ssm_state=16 if cfg.ssm_state else 0,

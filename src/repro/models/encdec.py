@@ -208,6 +208,10 @@ class EncDecLM:
     def cache_abstract(self, batch: int, max_seq: int):
         return self._cache_struct(batch, max_seq, abstract=True)
 
+    def cache_kinds(self) -> PyTree:
+        """Every cache leaf holds keys and values (see ``LM.cache_kinds``)."""
+        return {k: "kv" for k in ("self_k", "self_v", "cross_k", "cross_v")}
+
     def cache_axes(self) -> PyTree:
         ax = ("layers", "batch", "cache_seq", "kv_heads", None)
         fx = ("layers", "batch", "frames", "kv_heads", None)

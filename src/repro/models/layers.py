@@ -255,9 +255,9 @@ def attention_specs(cfg) -> Dict[str, ParamSpec]:
     return s
 
 
-def _qkv(params, cfg, x, positions, policy: ShardingPolicy,
-         rope: bool = True):
-    """Project to q (B,S,H,hd), k/v (B,S,KV,hd) with RoPE applied."""
+def _qkv(params, cfg, x, positions, policy: ShardingPolicy):
+    """Project to q (B,S,H,hd), k/v (B,S,KV,hd), with RoPE applied
+    unless the config has no positional encoding (``cfg.rope``)."""
     B, S, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     dt = x.dtype
@@ -274,7 +274,7 @@ def _qkv(params, cfg, x, positions, policy: ShardingPolicy,
     if cfg.qk_norm:
         q = head_rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = head_rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    if rope and positions is not None:
+    if cfg.rope and positions is not None:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope)
     q = constrain(q, policy, "batch", "seq", "heads", None)
@@ -310,8 +310,9 @@ def maybe_expand_gqa(q, k, v, policy: ShardingPolicy):
 
 
 def self_attention(q, k, v, *, causal: bool, window: int = 0,
-                   q_offset: int = 0):
-    """Exact chunked attention.  q (B,Sq,H,hd), k/v (B,Sk,KV,hd).
+                   q_offset: int = 0, scale: float = 0.0):
+    """Exact chunked attention.  q (B,Sq,H,hd), k/v (B,Sk,KV,hd); scores
+    are scaled by ``scale``, 0 meaning 1/sqrt(hd).
 
     Query chunking keeps the live score block at (Cq × Sk) instead of
     (Sq × Sk); with SWA the key block is additionally sliced to
@@ -321,7 +322,7 @@ def self_attention(q, k, v, *, causal: bool, window: int = 0,
     KV = k.shape[2]
     Sk = k.shape[1]
     rep = H // KV
-    scale = 1.0 / np.sqrt(hd)
+    scale = scale or 1.0 / np.sqrt(hd)
     qg = q.reshape(B, Sq, KV, rep, hd)
 
     def mask_for(qpos, kpos):

@@ -80,18 +80,29 @@ def _mixer(lp, cfg, kind, x, positions, policy, use_kernels=False):
         window = cfg.sliding_window if kind == "swa" else 0
         if use_kernels:
             from repro.kernels import ops
+            if cfg.attn_scale:           # the kernel scales by 1/sqrt(hd)
+                q = q * (cfg.attn_scale * np.sqrt(cfg.head_dim))
             o = ops.attention(q, k, v, causal=True, window=window)
         else:
-            o = L.self_attention(q, k, v, causal=True, window=window)
+            o = L.self_attention(q, k, v, causal=True, window=window,
+                                 scale=cfg.attn_scale)
         B, S = x.shape[:2]
         o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
         return o @ lp["attn"]["wo"].astype(x.dtype)
     return M.mamba_block(lp["mamba"], cfg, x, policy, use_kernels=use_kernels)
 
 
-def _ffn(lp, cfg, pos, x, policy, mesh):
+def _residual(cfg, x, branch):
+    """``x`` plus a residual branch, scaled by the config's multiplier."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch
+
+
+def _ffn(lp, cfg, pos, x, policy, mesh, dropless=False):
     if "moe" in lp:
-        return MOE.moe_block(lp["moe"], cfg, x, policy, mesh)
+        return MOE.moe_block(lp["moe"], cfg, x, policy, mesh,
+                             dropless=dropless)
     if "mlp" in lp:
         return L.mlp(lp["mlp"], x, policy), jnp.zeros((), jnp.float32)
     return None, jnp.zeros((), jnp.float32)   # pure-SSM archs: no FFN
@@ -100,13 +111,14 @@ def _ffn(lp, cfg, pos, x, policy, mesh):
 def _block(lp, cfg, pos, x, positions, policy, mesh, use_kernels=False):
     kind = cfg.layer_kind(pos)
     h = L.rmsnorm(lp["pre_mixer_norm"], x, cfg.norm_eps)
-    x = x + _mixer(lp, cfg, kind, h, positions, policy, use_kernels)
+    x = _residual(cfg, x, _mixer(lp, cfg, kind, h, positions, policy,
+                                 use_kernels))
     x = constrain(x, policy, "batch", "seq", "act_d")
     f, aux = _ffn(lp, cfg, pos,
                   L.rmsnorm(lp["pre_mlp_norm"], x, cfg.norm_eps),
                   policy, mesh)
     if f is not None:
-        x = x + f
+        x = _residual(cfg, x, f)
         x = constrain(x, policy, "batch", "seq", "act_d")
     return x, aux
 
@@ -149,10 +161,15 @@ class LM:
             is_leaf=lambda x: isinstance(x, tuple))
 
     # ---------------- embedding / head ----------------
-    def _embed(self, params, batch):
-        tokens = batch["tokens"]
+    def _embed_tokens(self, params, tokens):
         emb = jnp.take(params["embed"]["tok"].astype(self.compute_dtype),
                        tokens, axis=0)
+        if self.cfg.embedding_multiplier != 1.0:
+            emb = emb * self.cfg.embedding_multiplier
+        return emb
+
+    def _embed(self, params, batch):
+        emb = self._embed_tokens(params, batch["tokens"])
         ve = batch.get("vision_embeds")
         if ve is not None:
             emb = jax.lax.dynamic_update_slice_in_dim(
@@ -165,6 +182,8 @@ class LM:
         else:
             w = params["lm_head"].astype(x.dtype)
         logits = x @ w
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
         logits = L.mask_padded_vocab(logits, self.cfg)
         return constrain(logits, self.policy, "batch", "logit_seq", "vocab")
 
@@ -261,6 +280,19 @@ class LM:
     def cache_abstract(self, batch: int, max_seq: int):
         return self._cache(batch, max_seq, abstract=True)
 
+    def cache_kinds(self) -> PyTree:
+        """What each cache leaf holds, by its layer's kind: "kv" (keys
+        and values, a row per position) or "state" (recurrent state and
+        convolution tails, of a fixed size)."""
+        cfg = self.cfg
+        out = {}
+        for j in range(len(cfg.layer_pattern)):
+            if cfg.layer_kind(j) in ("attn", "swa"):
+                out[f"pos{j}"] = {"k": "kv", "v": "kv"}
+            else:
+                out[f"pos{j}"] = {k: "state" for k in M.MAMBA_CACHE_AXES}
+        return out
+
     def cache_axes(self) -> PyTree:
         cfg = self.cfg
         out = {}
@@ -313,7 +345,11 @@ class LM:
         v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot, 1)
 
         qg = q.reshape(B, 1, KV, H // KV, hd)
-        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) / np.sqrt(hd)
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k)
+        if cfg.attn_scale:
+            scores = scores * cfg.attn_scale
+        else:
+            scores = scores / np.sqrt(hd)
         scores = scores.astype(jnp.float32)
         idx = jnp.arange(S_c)
         if window:
@@ -331,8 +367,7 @@ class LM:
         """One serving step: tokens (B,) int32, pos scalar int32."""
         cfg = self.cfg
         P = len(cfg.layer_pattern)
-        x = jnp.take(params["embed"]["tok"].astype(self.compute_dtype),
-                     tokens, axis=0)                       # (B, d)
+        x = self._embed_tokens(params, tokens)             # (B, d)
         x = constrain(x, self.policy, "batch", "act_d")
 
         def superblock(x, xs):
@@ -344,19 +379,21 @@ class LM:
                 kind = cfg.layer_kind(j)
                 h = L.rmsnorm(lp["pre_mixer_norm"], x, cfg.norm_eps)
                 if kind in ("attn", "swa"):
-                    o, nc = self._decode_attn(lp, kind, h, lc, pos)
+                    with jax.named_scope("attn"):
+                        o, nc = self._decode_attn(lp, kind, h, lc, pos)
                 else:
-                    o, nc = M.mamba_decode(lp["mamba"], cfg, h, lc,
-                                           self.policy)
-                x = x + o
+                    with jax.named_scope("mamba"):
+                        o, nc = M.mamba_decode(lp["mamba"], cfg, h, lc,
+                                               self.policy)
+                x = _residual(cfg, x, o)
                 h2 = L.rmsnorm(lp["pre_mlp_norm"], x, cfg.norm_eps)
                 if "moe" in lp:
                     f, _ = MOE.moe_block(lp["moe"], cfg, h2[:, None, :],
                                          self.policy, self.mesh,
                                          dropless=True)
-                    x = x + f[:, 0, :]
+                    x = _residual(cfg, x, f[:, 0, :])
                 elif "mlp" in lp:
-                    x = x + L.mlp(lp["mlp"], h2, self.policy)
+                    x = _residual(cfg, x, L.mlp(lp["mlp"], h2, self.policy))
                 new_cache[f"pos{j}"] = nc
             return x, new_cache
 
@@ -367,6 +404,23 @@ class LM:
         return logits, new_cache
 
     # ---------------- prefill (build cache + logits) ----------------
+    def _prefill_attn(self, lp, kind, h, positions):
+        """Causal attention over the prompt, and its KV cache."""
+        cfg = self.cfg
+        q, k, v = L._qkv(lp["attn"], cfg, h, positions, self.policy)
+        window = cfg.sliding_window if kind == "swa" else 0
+        o = L.self_attention(q, k, v, causal=True, window=window,
+                             scale=cfg.attn_scale)
+        B, S = h.shape[:2]
+        o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
+        o = o @ lp["attn"]["wo"].astype(h.dtype)
+        if window and window < k.shape[1]:
+            # ring-buffer alignment: abs position p lives at slot p % window
+            s = k.shape[1] % window
+            return o, {"k": jnp.roll(k[:, -window:], s, axis=1),
+                       "v": jnp.roll(v[:, -window:], s, axis=1)}
+        return o, {"k": k, "v": v}
+
     def prefill(self, params, batch) -> Tuple[jax.Array, PyTree]:
         """Forward over a prompt, returning last-position logits and the
         populated KV/SSM cache (cache length == prompt length)."""
@@ -383,30 +437,20 @@ class LM:
                 kind = cfg.layer_kind(j)
                 h = L.rmsnorm(lp["pre_mixer_norm"], x, cfg.norm_eps)
                 if kind in ("attn", "swa"):
-                    q, k, v = L._qkv(lp["attn"], cfg, h, positions,
-                                     self.policy)
-                    window = cfg.sliding_window if kind == "swa" else 0
-                    o = L.self_attention(q, k, v, causal=True, window=window)
-                    B, S = x.shape[:2]
-                    o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
-                    o = o @ lp["attn"]["wo"].astype(x.dtype)
-                    if window and window < k.shape[1]:
-                        # ring-buffer alignment: abs position p lives at
-                        # slot p % window
-                        s = k.shape[1] % window
-                        nc = {"k": jnp.roll(k[:, -window:], s, axis=1),
-                              "v": jnp.roll(v[:, -window:], s, axis=1)}
-                    else:
-                        nc = {"k": k, "v": v}
+                    with jax.named_scope("attn"):
+                        o, nc = self._prefill_attn(lp, kind, h, positions)
                 else:
-                    o, hfin, tails = _mamba_prefill(lp["mamba"], cfg, h,
-                                                    self.policy)
+                    with jax.named_scope("mamba"):
+                        o, hfin, tails = _mamba_prefill(lp["mamba"], cfg, h,
+                                                        self.policy)
                     nc = {"h": hfin, **tails}
-                x = x + o
+                x = _residual(cfg, x, o)
                 h2 = L.rmsnorm(lp["pre_mlp_norm"], x, cfg.norm_eps)
-                f, _ = _ffn(lp, cfg, j, h2, self.policy, self.mesh)
+                # a serving prefill drops no token, as decode_step does not
+                f, _ = _ffn(lp, cfg, j, h2, self.policy, self.mesh,
+                            dropless=True)
                 if f is not None:
-                    x = x + f
+                    x = _residual(cfg, x, f)
                 new_cache[f"pos{j}"] = nc
             return x, new_cache
 

@@ -28,16 +28,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import ParamSpec
+from repro.models.layers import ParamSpec, mlp, mlp_specs
 from repro.sharding.policy import ShardingPolicy
 
 CAPACITY_FACTOR = 1.25
 
 
 def moe_specs(cfg) -> Dict[str, ParamSpec]:
-    E, d, f = cfg.moe_num_experts, cfg.d_model, cfg.moe_d_ff
-    return {
-        "router": ParamSpec((d, E), (None, None)),   # replicated (tiny)
+    """The router scores all ``moe_num_experts``; the expert weights are
+    the ``moe_held`` experts this model holds (its expert-parallel
+    share), and a shared expert, when the config has one, sees every
+    token."""
+    E, d, f = cfg.moe_held, cfg.d_model, cfg.moe_d_ff
+    s = {
+        "router": ParamSpec((d, cfg.moe_num_experts), (None, None)),
         "w_gate": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff"),
                             cast=True),
         "w_up": ParamSpec((E, d, f), ("experts", "d_model", "moe_ff"),
@@ -45,6 +49,9 @@ def moe_specs(cfg) -> Dict[str, ParamSpec]:
         "w_down": ParamSpec((E, f, d), ("experts", "moe_ff", "d_model"),
                             cast=True),
     }
+    if cfg.moe_shared_d_ff:
+        s["shared"] = mlp_specs(d, cfg.moe_shared_d_ff)
+    return s
 
 
 def capacity(tokens: int, k: int, num_experts: int,
@@ -121,7 +128,17 @@ def _local_moe(x, router, w_gate, w_up, w_down, *, cfg, ep_axes, fsdp_axes,
 
 def moe_block(params, cfg, x: jax.Array, policy: ShardingPolicy,
               mesh, dropless: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """x (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
+    """x (B, S, d) -> (y (B, S, d), aux_loss scalar): the held experts'
+    part of the routed mixture, plus the shared expert if there is one."""
+    with jax.named_scope("moe"):
+        y, aux = _routed(params, cfg, x, policy, mesh, dropless)
+    if "shared" in params:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(params["shared"], x, policy)
+    return y, aux
+
+
+def _routed(params, cfg, x, policy, mesh, dropless):
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape

@@ -32,6 +32,9 @@ METRIC_SCHEMA: Dict[str, tuple] = {
     "dump.bytes_deduped": ("counter", "bytes",
                            "chunk-grain dedup savings at commit"),
     "dump.frozen_s": ("histogram", "s", "stop-the-world frozen window"),
+    "dump.captured_bytes": ("counter", "bytes",
+                            "device bytes copied to the host by each "
+                            "frozen capture"),
     "dump.pending_stall_s": ("histogram", "s",
                              "async writer join timeouts "
                              "(PendingWriteStalled)"),
@@ -75,6 +78,12 @@ METRIC_SCHEMA: Dict[str, tuple] = {
                            "decode-server compute copies of the weights "
                            "built: one per load or restore, none per "
                            "token"),
+    "serve.bytes.params": ("gauge", "bytes",
+                           "decode-server weights on the device"),
+    "serve.bytes.kv": ("gauge", "bytes",
+                       "decode-server key/value cache, padded to max_seq"),
+    "serve.bytes.state": ("gauge", "bytes",
+                          "decode-server recurrent state and conv tails"),
 }
 
 

@@ -23,20 +23,28 @@ from repro.obs import trace as obs_trace
 from repro.sharding.policy import ShardingPolicy
 
 
+class SequenceFull(RuntimeError):
+    """A decode step would write a key/value row at or past ``max_seq``."""
+
+
 class DecodeServer:
     def __init__(self, cfg: ModelConfig, policy: ShardingPolicy, mesh,
                  run_dir: str, max_seq: int = 256,
                  compute_dtype=jnp.float32,
                  options: Optional[CheckpointOptions] = None,
                  session: Optional[CheckpointSession] = None,
-                 model=None):
+                 model=None, param_dtype=jnp.float32):
         self.cfg = cfg
         # `model=` lets a fleet of replicas share one model (and one jit
-        # cache) instead of recompiling per server
+        # cache) instead of recompiling per server, and its dtypes
         self.model = model if model is not None else build_model(
-            cfg, policy, mesh, compute_dtype=compute_dtype, remat=False)
+            cfg, policy, mesh, compute_dtype=compute_dtype,
+            param_dtype=param_dtype, remat=False)
         self.max_seq = max_seq
-        self.params = None             # the held (f32 master) weights
+        # full attention writes a key/value row per position into a cache
+        # of max_seq rows; a windowed or recurrent layer never fills up
+        self._kv_rows = max_seq if "attn" in cfg.layer_pattern else None
+        self.params = None             # the held weights (param_dtype)
         self.cache = None
         self.tokens: Optional[np.ndarray] = None       # generated so far
         self.pos = 0
@@ -44,8 +52,8 @@ class DecodeServer:
             if (options is not None and options.restore_mode == "lazy"
                     and options.critical_states is None):
                 # resume-before-read default: the decode loop touches
-                # params immediately; the (large) KV cache streams in
-                # behind the resumed server
+                # params immediately; the cache streams in behind the
+                # resumed server and is joined before its first step
                 options = options.replace(
                     critical_states=("serve_state/params",))
             session = CheckpointSession(run_dir, options, mesh=mesh)
@@ -94,6 +102,25 @@ class DecodeServer:
 
     def load(self, params) -> None:
         self.params = params
+        self._gauge_bytes()
+
+    def _gauge_bytes(self) -> None:
+        """Gauge what the server holds on the device: its weights
+        (``serve.bytes.params``) and its cache by the kind of layer each
+        leaf belongs to (``serve.bytes.kv``: keys and values;
+        ``serve.bytes.state``: recurrent state and convolution tails)."""
+        if obs_metrics.REGISTRY is None:
+            return
+        if self._params is not None:
+            obs_metrics.gauge_set("serve.bytes.params", float(sum(
+                x.nbytes for x in jax.tree.leaves(self._params))))
+        if self.cache is not None:
+            held = {"kv": 0, "state": 0}
+            for kind, leaf in zip(jax.tree.leaves(self.model.cache_kinds()),
+                                  jax.tree.leaves(self.cache)):
+                held[kind] += leaf.nbytes
+            for kind, n in held.items():
+                obs_metrics.gauge_set(f"serve.bytes.{kind}", float(n))
 
     # ------------------------------------------------------------- serving
     def start(self, batch: Dict[str, Any]) -> None:
@@ -104,30 +131,31 @@ class DecodeServer:
                                       {k: jnp.asarray(v)
                                        for k, v in batch.items()})
         self.cache = self._pad_cache(cache, self.max_seq)
+        self._gauge_bytes()
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         self.tokens = np.concatenate([np.asarray(prompt, np.int32),
                                       nxt[:, None]], axis=1)
         self.pos = S
 
     def _pad_cache(self, cache, max_seq):
-        """Pad the *attention* KV seq dim (axis 2 of (L,B,S,KV,hd)) to
-        max_seq.  Keyed by leaf name — SSM states are 5-D too and must not
-        be touched."""
-        def pad(leaf):
-            if leaf.ndim == 5 and leaf.shape[2] < max_seq:
-                w = [(0, 0)] * 5
-                w[2] = (0, max_seq - leaf.shape[2])
-                return jnp.pad(leaf, w)
-            return leaf
+        """Pad the prefill's key/value leaves to the rows the decode cache
+        has at ``max_seq`` (``model.cache_abstract``).  Leaves are told
+        apart by their layer's kind (``model.cache_kinds``): recurrent
+        state has a fixed size and is never padded."""
+        B = jax.tree.leaves(cache)[0].shape[1]
 
-        def walk(node):
-            if isinstance(node, dict):
-                return {k: (pad(v) if k in ("k", "v", "self_k", "self_v")
-                            and hasattr(v, "ndim") else walk(v))
-                        for k, v in node.items()}
-            return node
+        def pad(kind, leaf, want):
+            if kind != "kv" or leaf.shape == want.shape:
+                return leaf
+            short = [w - n for n, w in zip(leaf.shape, want.shape)]
+            if min(short) < 0:
+                raise ValueError(f"a cache leaf of shape {leaf.shape} does "
+                                 f"not fit max_seq={max_seq} "
+                                 f"{want.shape}")
+            return jnp.pad(leaf, [(0, n) for n in short])
 
-        return walk(cache)
+        return jax.tree.map(pad, self.model.cache_kinds(), cache,
+                            self.model.cache_abstract(B, max_seq))
 
     def decode_until(self, target_pos: int,
                      preempt: Optional[Callable[[], bool]] = None,
@@ -174,6 +202,10 @@ class DecodeServer:
                 time.sleep(0.25)                   # injected straggler
             # first-touch join of the lazily-streaming cache
             self._finish_lazy_restore()
+            if self._kv_rows is not None and self.pos >= self._kv_rows:
+                raise SequenceFull(
+                    f"position {self.pos} is past the {self._kv_rows} "
+                    f"key/value rows of the cache (max_seq)")
             with obs_trace.span("serve.step", pos=self.pos):
                 last = jnp.asarray(self.tokens[:, -1])
                 logits, self.cache = self._decode(self._compute_params(),
@@ -254,6 +286,7 @@ class DecodeServer:
                 self._pending_cache_template = template["cache"]
             else:
                 self.cache = engine.retree(template["cache"], raw["cache"])
+            self._gauge_bytes()
             return self.pos
         if template["params"] is None or template["cache"] is None:
             raw = self.session.restore(step=step, wait="all")
@@ -261,11 +294,13 @@ class DecodeServer:
             serve = raw["serve_state"]
             self.params = engine.retree(template["params"], serve["params"])
             self.cache = engine.retree(template["cache"], serve["cache"])
+            self._gauge_bytes()
             return self.pos
         restored = self.session.restore_into(template, state="serve_state",
                                              step=step)
         self.params = restored["params"]
         self.cache = restored["cache"]
+        self._gauge_bytes()
         return self.pos
 
     def _finish_lazy_restore(self) -> None:
@@ -277,3 +312,4 @@ class DecodeServer:
         full = self.session.restore_barrier()
         self.cache = self.session.engine.retree(
             template, full["serve_state"]["cache"])
+        self._gauge_bytes()

@@ -165,3 +165,64 @@ def test_mamba2_decode_step_casts_no_weight(topo, tmp_path):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < V5E_HBM_BYTES, total
+
+
+def _granite_cut():
+    """The benchmark's cut of granite-4.0-h-small: one period of ten
+    layers, 9 of the 72 experts held, an eighth of the vocabulary."""
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("granite-4.0-h-small"),
+                               num_layers=10, moe_experts_held=9,
+                               vocab_size=12544)
+
+
+@pytest.fixture(scope="module")
+def granite_server(topo, tmp_path_factory):
+    """The serve cell's replica on one described chip: bf16 weights held
+    and computed on, B=8, max_seq 16,384."""
+    from repro.runtime.server import DecodeServer
+    from repro.sharding import get_policy
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    srv = DecodeServer(_granite_cut(), get_policy("baseline"), mesh,
+                       str(tmp_path_factory.mktemp("granite")),
+                       max_seq=16384, compute_dtype=jnp.bfloat16,
+                       param_dtype=jnp.bfloat16)
+    one = SingleDeviceSharding(topo.devices[0])
+    held = jax.tree.map(lambda a: _spec(one, a.shape, a.dtype),
+                        srv.model.init_abstract())
+    return srv, mesh, one, held
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_cut_fits_one_chip_and_casts_no_weight(granite_server,
+                                                        program):
+    """The granite cut's decode step at B=8 over a 16,384-row cache, and
+    its prefill of 8 prompts of 2,048 tokens, as the server runs them:
+    each fits one chip, and neither converts a weight it reads in the
+    compute dtype (the server holds bf16 and computes in bf16, so it
+    builds no compute copy).  Norm gains, conv taps and the SSM terms
+    are read in f32 by design: a few KB a layer."""
+    from repro.models.layers import ParamSpec
+    srv, mesh, one, held = granite_server
+    assert srv.model.compute_params(held) is held
+    weights = {f"bf16{list(s.shape)}".replace(" ", "")
+               for s in jax.tree.leaves(
+                   srv.model._specs,
+                   is_leaf=lambda x: isinstance(x, ParamSpec)) if s.cast}
+    with jax.sharding.set_mesh(mesh):
+        if program == "decode":
+            cache = jax.tree.map(lambda a: _spec(one, a.shape, a.dtype),
+                                 srv.model.cache_abstract(8, 16384))
+            compiled = srv._decode.lower(
+                held, cache, _spec(one, (8,), jnp.int32),
+                _spec(one, (), jnp.int32)).compile()
+        else:
+            compiled = srv._prefill.lower(
+                held, {"tokens": _spec(one, (8, 2048), jnp.int32)}).compile()
+    converts = _converts_of(compiled.as_text())
+    assert converts                                 # the search ran
+    assert [c for c in converts if c[1] in weights] == []
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
