@@ -115,10 +115,12 @@ SPAN_SCHEMA: Dict[str, tuple] = {
                               "on the host"),
     "train.sync": ("runtime", "the wait for the step's loss on the host "
                               "(inside train.step)"),
-    "serve.step": ("runtime", "one decoded token: stage, dispatch, "
-                              "fetch, append"),
-    "serve.sync": ("runtime", "the wait for the token's argmax on the "
-                              "host (inside serve.step)"),
+    "serve.step": ("runtime", "one decoded token: wait for tokens "
+                              "older than the lag, dispatch the step "
+                              "and its argmax, start the token's copy "
+                              "to the host"),
+    "serve.sync": ("runtime", "a wait for fetched tokens, one or several, "
+                              "on the host (inside serve.step)"),
     "serve.cast": ("runtime", "build of the decode server's compute "
                               "copy of its weights, at the first prefill "
                               "or token after a load or restore"),

@@ -325,13 +325,15 @@ def test_resumed_train_step_holds_one_sync(trained, made):
 
 
 def test_decode_loop_spans_each_token(tmp_path, made):
-    """decode_until(pos + k) gives k serve.step spans, each holding one
-    serve.sync; with no tracer the same loop makes no span."""
+    """decode_until(pos + k) gives k serve.step spans, one per token;
+    every serve.sync (a wait for fetched tokens) nests in a serve.step,
+    and each step's ``ahead`` counts the earlier steps still in flight
+    when it was dispatched; with no tracer the same loop makes no span."""
     import jax
     from repro.configs import get_smoke_config
     from repro.data import TokenPipeline
     from repro.launch.mesh import make_mesh
-    from repro.runtime.server import DecodeServer
+    from repro.runtime.server import _LAG, DecodeServer
     from repro.sharding import get_policy
     cfg = get_smoke_config("mamba2-2.7b")
     mesh = make_mesh((1,), ("data",))
@@ -345,15 +347,19 @@ def test_decode_loop_spans_each_token(tmp_path, made):
     tr = trace.Tracer()
     trace.install(tr)
     try:
-        srv.decode_until(pos + 3)
+        srv.decode_until(pos + 5)
     finally:
         trace.uninstall()
     steps = [sp for sp in tr.spans if sp.name == "serve.step"]
     syncs = [sp for sp in tr.spans if sp.name == "serve.sync"]
-    assert [sp.attrs["pos"] for sp in steps] == [pos, pos + 1, pos + 2]
-    assert sorted(sp.parent_id for sp in syncs) == sorted(
-        sp.span_id for sp in steps)
-    assert srv.tokens.shape[1] == 8 + 1 + 2 + 3
+    assert [sp.attrs["pos"] for sp in steps] == list(range(pos, pos + 5))
+    step_ids = {sp.span_id for sp in steps}
+    assert syncs and all(sp.parent_id in step_ids for sp in syncs)
+    ahead = [sp.attrs["ahead"] for sp in steps]
+    assert ahead[0] == 0 and all(0 < a <= _LAG for a in ahead[1:])
+    # the drain at return runs inside the call's last step
+    assert steps[-1].span_id in {sp.parent_id for sp in syncs}
+    assert srv.tokens.shape[1] == 8 + 1 + 2 + 5
 
 
 # ----------------------------------------------------- spans and nesting

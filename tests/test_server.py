@@ -1,6 +1,12 @@
 """Decode-server serving-state snapshots: checkpoint a half-finished
 generation, restore into a fresh server, continue token-exact (the paper's
 inference-side story — Modal/MemVerge cold-start snapshots)."""
+import functools
+import os
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -217,3 +223,107 @@ def test_restore_rebuilds_the_compute_copy(path, tmp_path):
         metrics.uninstall()
     np.testing.assert_array_equal(expected, got)
     assert _leaves_equal(srv.params, weights_b)
+
+
+# ------------------------------------------------- the pipelined decode loop
+@functools.lru_cache(maxsize=None)
+def _shared(arch):
+    """One mesh and model per architecture, so the chunk sizes share its
+    compiled programs."""
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.encdec import build_model
+    mesh = make_host_mesh(1, 1)
+    return mesh, build_model(get_smoke_config(arch), POLICY, mesh,
+                             compute_dtype=jnp.float32, remat=False)
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 5])     # below, at, above _LAG
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "qwen1.5-0.5b",
+                                  "granite-4.0-h-small"])
+def test_pipelined_decode_matches_step_by_step(arch, chunk, tmp_path):
+    """decode_until fetches tokens behind the device, yet gives the tokens
+    and the final cache of the step-by-step reference bit for bit, in
+    calls of any length.  A preemption mid-call and an injected failure
+    both leave the history caught up with ``pos``, and the preemption's
+    snapshot resumes token-exact in a fresh server."""
+    from repro.runtime.trainer import SimulatedFailure
+    mesh, model = _shared(arch)
+    cfg = get_smoke_config(arch)
+    run = str(tmp_path / "srv")
+    srv = DecodeServer(cfg, POLICY, mesh, run, max_seq=64, model=model)
+    params = srv.model.init(jax.random.key(0))
+    srv.load(params)
+    batch = _prompt(cfg, B=2, S=8)
+    n = 6 + 2 * chunk
+    want_tokens, want_cache = _drive_model(srv, params, batch, n)
+    want = np.concatenate([np.asarray(batch["tokens"], np.int32),
+                           want_tokens], axis=1)
+
+    def caught_up():
+        assert srv.tokens.shape[1] == srv.pos + 1
+        np.testing.assert_array_equal(srv.tokens, want[:, :srv.pos + 1])
+
+    srv.start(batch)
+    while srv.pos < 8 + 3:
+        srv.decode_until(srv.pos + chunk)
+        caught_up()
+
+    polls = []                         # fires once `chunk` steps are out
+    out = srv.decode_until(srv.pos + chunk + 2,
+                           preempt=lambda: polls.append(1) or
+                           len(polls) > chunk)
+    assert out["preempted"] and out["steps"] == chunk
+    caught_up()
+    at = srv.pos
+
+    with pytest.raises(SimulatedFailure):
+        srv.decode_until(srv.pos + chunk + 2, fail_at=srv.pos + chunk)
+    caught_up()
+    srv.decode_until(8 + n)
+    caught_up()
+    assert _leaves_equal(srv.cache, want_cache)
+
+    cold = DecodeServer(cfg, POLICY, mesh, run, max_seq=64, model=model)
+    cold.restore(step=at)
+    assert cold.pos == at
+    np.testing.assert_array_equal(cold.decode(chunk + 1),
+                                  want[:, :at + chunk + 2])
+
+
+# four virtual devices only exist in a process of their own
+_SHARDED = textwrap.dedent("""
+    import sys, tempfile
+    import jax
+    from repro.configs import get_smoke_config
+    from repro.data import TokenPipeline
+    from repro.launch.mesh import make_host_mesh
+    from repro.runtime.server import DecodeServer
+    from repro.sharding import get_policy
+    cfg = get_smoke_config(sys.argv[1])
+    mesh = make_host_mesh(data=4, model=1)
+    srv = DecodeServer(cfg, get_policy("baseline"), mesh, tempfile.mkdtemp(),
+                       max_seq=64)
+    on = (srv.model.param_shardings() if sys.argv[2] == "mesh"
+          else jax.devices()[0])
+    srv.load(jax.device_put(srv.model.init(jax.random.key(0)), on))
+    srv.start(TokenPipeline(cfg, 4, 8, seed=9).next())
+    for _ in range(2):
+        srv.decode_until(srv.pos + 5)
+    print("ENTRIES", srv._decode._cache_size(), srv.tokens.shape[1] - srv.pos)
+""")
+
+
+@pytest.mark.parametrize("placed", ["mesh", "device"])
+def test_pipelined_decode_compiles_once_on_committed_weights(placed):
+    """Weights committed across four devices, or to one, make every
+    argmax output committed too: the token staged from the host for each
+    call's first step is placed as those are, so the decode program keeps
+    one entry."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", _SHARDED, "qwen1.5-0.5b", placed],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "ENTRIES 1 1" in r.stdout, r.stdout[-2000:]
